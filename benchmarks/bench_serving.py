@@ -58,6 +58,7 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.core import cost_model as CM
 from repro.kernels.backend import FaultConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model_fns
 from repro.serving import (
     PRIORITY_BATCH,
@@ -1463,6 +1464,7 @@ def main() -> None:
              "and exit (the CI artifact check)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
     if args.validate:
         with open(args.validate) as f:
             validate_report(json.load(f))

@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams as _CompilerParams
-
 from . import prng
 
 DEF_BM, DEF_BN, DEF_BK = 128, 128, 512
@@ -166,7 +164,7 @@ def crossbar_mac_pallas(
             pltpu.VMEM((1, bn), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(x.astype(jnp.float32), w.astype(jnp.float32), seed)

@@ -9,9 +9,14 @@ Responsibilities:
         paged_attention*) route through the active device backend
         (`repro.kernels.backend` — Sim by default, the seam for
         hardware-in-the-loop later);
-      - the Sim implementations (`*_sim` below) then pick compiled Pallas
-        on TPU, `pltpu.InterpretParams` emulation on CPU (tests), or the
-        pure-jnp oracle where a caller asks for it.
+      - the Sim implementations (`*_sim` below) then pick compiled
+        Pallas on TPU (:func:`tpu_kernels`).  Off TPU the serving kernels
+        (paged attention, chunked prefill, serving stochastic rounding)
+        run their pure-jnp oracles; the analog kernels (crossbar MAC, WTA
+        counts, stochastic rounding for training) run in the Pallas TPU
+        interpreter.
+  * under a serving mesh, ``shard_map`` around every serving kernel
+    (:func:`shard_over_kv_heads`): GSPMD cannot partition a Mosaic call.
 
 All wrappers accept arbitrary leading batch dims on ``x``.
 """
@@ -23,6 +28,9 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
+
+from repro import parallel
 
 from . import backend as _backend
 from . import crossbar_mac as _cb
@@ -33,9 +41,12 @@ from repro.core.physics import BOLTZMANN_K, PROBIT_SCALE
 
 
 def _interpret_mode():
-    from .compat import interpret_mode
-
-    return interpret_mode()
+    """``pallas_call(interpret=...)`` for the analog kernels: compiled on
+    TPU, the Pallas TPU interpreter on the CPU backend (tests and CPU
+    training runs of the analog modes)."""
+    if tpu_kernels():
+        return False
+    return pltpu.InterpretParams()
 
 
 def _pad_to(x: jax.Array, m: int, axis: int) -> jax.Array:
@@ -383,6 +394,69 @@ def wta_counts_reference(
 # ---------------------------------------------------------------------------
 
 
+def tpu_kernels() -> bool:
+    """Whether the serving kernels (paged decode, chunked prefill, int8
+    stochastic rounding) run as compiled Pallas.  They do on TPU; on
+    every other backend their pure-jnp oracles stand in.  The one switch
+    the serving path asks (tests steer it by patching this function)."""
+    return jax.default_backend() == "tpu"
+
+
+def shard_over_kv_heads(kernel, mesh):
+    """Run a paged attention kernel once per chip of ``mesh``'s model axis.
+
+    ``kernel(q, k_pages, v_pages, table, idx, *, k_scale, v_scale, **kw)``
+    is :func:`paged_attention_pallas` or
+    :func:`paged_prefill_attention_pallas`.  Each chip attends with its
+    own ``Hkv / |model|`` KV heads' pages (the pool's sharding: kv_heads
+    over "model") and the matching query heads, then the readouts are
+    all-gathered over heads.  So every chip leaves with the whole (…, H,
+    Dh) readout, and no later contraction is split across chips: the
+    output projection runs as on one chip.  Where the model axis does not
+    divide Hkv the pool is replicated and each chip runs every head.
+    Table and position arguments are replicated scalars."""
+    from jax.sharding import PartitionSpec as P
+
+    m = dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
+
+    def run(q, k_pages, v_pages, table, idx, *, k_scale=None,
+            v_scale=None, **kw):
+        ax = "model" if k_pages.shape[2] % m == 0 else None
+        heads = P(None, ax, None)            # q / readout: (B|S, H, Dh)
+        pages = P(None, None, ax, None)      # (P, bs, Hkv, Dh)
+        scales = P(None, None, ax)           # (P, bs, Hkv)
+        args = [q, k_pages, v_pages, table, idx]
+        specs = [heads, pages, pages, P(), P()]
+        if k_scale is not None:
+            args += [k_scale, v_scale]
+            specs += [scales, scales]
+
+        def body(q, kp, vp, t, i, *sc):
+            out = kernel(
+                q, kp, vp, t, i,
+                k_scale=sc[0] if sc else None,
+                v_scale=sc[1] if sc else None,
+                **kw,
+            )
+            if ax is None:
+                return out
+            return jax.lax.all_gather(out, "model", axis=1, tiled=True)
+
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=tuple(specs), out_specs=P(),
+            check_vma=False,
+        )(*args)
+
+    return run
+
+
+def _on_kernel_mesh(kernel):
+    """``kernel``, run per chip over the KV heads when a kernel mesh is
+    installed (:func:`repro.parallel.kernel_mesh`)."""
+    mesh = parallel.current_kernel_mesh()
+    return kernel if mesh is None else shard_over_kv_heads(kernel, mesh)
+
+
 def paged_attention(
     q: jax.Array,        # (B, H, Dh)
     k_pages: jax.Array,  # (P, bs, Hkv, Dh) — cache dtype or int8 codes
@@ -433,13 +507,13 @@ def paged_attention_sim(
     tests/test_kernels.py (interpret mode on small shapes)."""
     from . import paged_attention as _pa
 
-    if jax.default_backend() != "tpu":
+    if not tpu_kernels():
         return ref.paged_attention_ref(
             q, k_pages, v_pages, table, pos,
             kind=kind, local_window=local_window, softcap=softcap,
             k_scale=k_scale, v_scale=v_scale,
         )
-    return _pa.paged_attention_pallas(
+    return _on_kernel_mesh(_pa.paged_attention_pallas)(
         q, k_pages, v_pages, table, pos,
         kind=kind, local_window=local_window, softcap=softcap,
         k_scale=k_scale, v_scale=v_scale,
@@ -498,13 +572,13 @@ def paged_prefill_attention_sim(
     path and the int8 fused-dequant math on every backend."""
     from . import prefill_attention as _pf
 
-    if jax.default_backend() != "tpu":
+    if not tpu_kernels():
         return ref.prefill_attention_ref(
             q, k_pages, v_pages, table, q0,
             kind=kind, local_window=local_window, softcap=softcap,
             k_scale=k_scale, v_scale=v_scale,
         )
-    return _pf.paged_prefill_attention_pallas(
+    return _on_kernel_mesh(_pf.paged_prefill_attention_pallas)(
         q, k_pages, v_pages, table, q0,
         kind=kind, local_window=local_window, softcap=softcap,
         k_scale=k_scale, v_scale=v_scale,
@@ -593,13 +667,24 @@ def stoch_round_serving_sim(
     x2d = x.reshape((-1, shape[-1])).astype(jnp.float32)
     xp = _pad_to(_pad_to(x2d, _sr.DEF_BM, 0), _sr.DEF_BN, 1)
     seed_u = jnp.asarray(seed).astype(jnp.uint32)
-    if jax.default_backend() != "tpu":
+    if not tpu_kernels():
         out = ref.stoch_round_ref(xp, seed_u, step=step, lo=lo, hi=hi)
     else:
         seed_arr = jax.lax.bitcast_convert_type(seed_u, jnp.int32).reshape(1)
-        out = _sr.stoch_round_pallas(
-            xp, seed_arr, step=step, lo=lo, hi=hi, interpret=False
+        kernel = functools.partial(
+            _sr.stoch_round_pallas, step=step, lo=lo, hi=hi, interpret=False
         )
+        mesh = parallel.current_kernel_mesh()
+        if mesh is not None:
+            # GSPMD cannot partition a Mosaic call: every chip rounds the
+            # whole (small) write, with the same counters and bits
+            from jax.sharding import PartitionSpec as P
+
+            kernel = jax.shard_map(
+                kernel, mesh=mesh, in_specs=P(), out_specs=P(),
+                check_vma=False,
+            )
+        out = kernel(xp, seed_arr)
     return out[: x2d.shape[0], : x2d.shape[1]].reshape(shape)
 
 

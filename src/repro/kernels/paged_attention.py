@@ -23,7 +23,7 @@ Grid: (B, W) with W = table width (blocks per slot), W innermost and
 sequential — the accumulator carries across a slot's blocks.
 
 The pure-jnp oracle is kernels/ref.py:paged_attention_ref; CPU tests run
-this kernel in interpret mode (see compat.py) and the serving engine off
+this kernel in interpret mode and the serving engine off
 TPU uses the gather + shared-attend jnp path in models/attention.py.
 """
 
@@ -35,8 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .compat import CompilerParams as _CompilerParams
 
 NEG_INF = -2.0e38
 
@@ -206,7 +204,7 @@ def paged_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # W must stay sequential (the scratch accumulator carries across
             # a slot's blocks); B revisits scratch only after a full W sweep.
             dimension_semantics=("arbitrary", "arbitrary"),

@@ -42,8 +42,10 @@ def uniform01(bits: jnp.ndarray) -> jnp.ndarray:
     """uint32 bits -> float32 uniform in the open interval (0, 1).
 
     Uses the top 24 bits (exact in f32) plus a half-ulp offset so log() is
-    always finite."""
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(
+    always finite.  The 24-bit value goes through int32 on its way to f32:
+    Mosaic has no uint32 -> f32 conversion, and the value is the same."""
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(
         1.0 / (1 << 24)
     ) + jnp.float32(1.0 / (1 << 25))
 

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams as _CompilerParams
-
 from . import prng
 
 DEF_BM = 128
@@ -107,7 +105,7 @@ def wta_counts_pallas(
         out_specs=pl.BlockSpec((bm, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
     )(z.astype(jnp.float32), seed)

@@ -7,17 +7,30 @@ all-reduce per step, which is what DCI-connected pods sustain).
 
 Functions, not module-level constants: importing this module never touches
 jax device state (the dry-run must set XLA_FLAGS before first jax init).
+
+Every mesh here has Auto axes: the sharding rules place arrays with
+``NamedSharding`` and leave the partitioning of the ops in between to
+GSPMD.  (``jax.make_mesh`` defaults to Explicit axes, under which the
+paged pool's scatter raises ``ShardingTypeError``.)
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1, data: int | None = None):
@@ -41,7 +54,6 @@ def make_host_mesh(model: int = 1, data: int | None = None):
             f"mesh ({data}, {model}) needs {data * model} devices, "
             f"have {n}"
         )
-    return jax.make_mesh(
-        (data, model), ("data", "model"),
-        devices=jax.devices()[: data * model],
+    return auto_mesh(
+        (data, model), ("data", "model"), devices=jax.devices()[: data * model]
     )

@@ -16,8 +16,25 @@ import jax
 
 from repro.checkpoint import latest_step, load_checkpoint
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model_fns
 from repro.serving import ServeConfig, ServingEngine, StaticServingEngine
+
+
+def device_line() -> str:
+    """The device the run is on, as JAX reports it."""
+    d = jax.devices()
+    return (
+        f"device: platform={d[0].platform} kind={d[0].device_kind} "
+        f"count={len(d)}"
+    )
+
+
+def init_params(cfg, seed: int = 0):
+    """Seeded random params, built in one jitted program (at published
+    widths an op-by-op init dispatches thousands of small ops)."""
+    fns = get_model_fns(cfg)
+    return jax.jit(lambda key: fns.init(key, cfg))(jax.random.PRNGKey(seed))
 
 
 def main() -> None:
@@ -122,13 +139,14 @@ def main() -> None:
                          "failures and sanity evictions")
     ap.add_argument("--ckpt-dir")
     args = ap.parse_args()
+    print(device_line())
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     cfg = dataclasses.replace(
         cfg, wta_head=args.wta, kv_cache_dtype=args.kv_dtype
     )
-    fns = get_model_fns(cfg)
-    params = fns.init(jax.random.PRNGKey(0), cfg)
+    params = init_params(cfg)
     if args.ckpt_dir:
         step = latest_step(args.ckpt_dir)
         if step is not None:

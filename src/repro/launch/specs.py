@@ -10,11 +10,13 @@ no device allocation, for the step function the shape's kind lowers:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from repro import parallel
 from repro.configs import SHAPES, get_config
 from repro.configs.shapes import ShapeSpec
 from repro.models import ModelConfig, get_model_fns
@@ -385,6 +387,19 @@ def paged_cache_shardings(
     return SH.cache_shardings(sds, mesh, cfg, batch)
 
 
+def on_kernel_mesh(fn, mesh):
+    """``fn`` traced with ``mesh`` installed as the kernel mesh, so each
+    Pallas kernel it reaches runs under ``shard_map``
+    (:func:`repro.kernels.ops.shard_over_kv_heads`)."""
+
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with parallel.kernel_mesh(mesh):
+            return fn(*args, **kwargs)
+
+    return run
+
+
 def make_sharded_paged_entry_points(
     cfg: ModelConfig, mesh, *, batch: int, n_pages: int, block_size: int,
     speculate_k: int = 0, n_redundant: int = 1, sat_threshold: float = 1e6,
@@ -416,8 +431,12 @@ def make_sharded_paged_entry_points(
 
     The block table, ``BlockAllocator``, and the content-hash prefix
     index stay HOST-GLOBAL: any slot may map any page, so prefix sharing
-    and copy-on-write work across shards unchanged; GSPMD inserts the
-    cross-shard page gathers.
+    and copy-on-write work across shards unchanged; off TPU, GSPMD
+    inserts the cross-shard page gathers of the jnp attention.  On TPU
+    the steps trace under :func:`on_kernel_mesh`: each Pallas kernel
+    runs in ``shard_map`` over the model axis (its chip's KV heads) and
+    all-gathers its readout, and ``ServeConfig.validate`` refuses a data
+    axis > 1 (the kernels read only their own chip's pages).
 
     Donation and compile discipline match the unsharded entry points
     (cache donated everywhere; ``bucket`` the only static argument of the
@@ -448,9 +467,12 @@ def make_sharded_paged_entry_points(
     vec_sh = NamedSharding(mesh, PartitionSpec(bax))
     mat_sh = NamedSharding(mesh, PartitionSpec(bax, None))
     serve_step = jax.jit(
-        make_paged_serve_step(
-            cfg, n_redundant=n_redundant, sat_threshold=sat_threshold,
-            entropy_floor=entropy_floor,
+        on_kernel_mesh(
+            make_paged_serve_step(
+                cfg, n_redundant=n_redundant, sat_threshold=sat_threshold,
+                entropy_floor=entropy_floor,
+            ),
+            mesh,
         ),
         donate_argnums=(1,),
         in_shardings=(params_sh, cache_sh, mat_sh, vec_sh, mat_sh, vec_sh),
@@ -464,7 +486,7 @@ def make_sharded_paged_entry_points(
     # ``bucket`` rides as the LAST positional arg here; the thin kwarg
     # shim below keeps the engine's ``(*args, bucket=...)`` call site
     # layout-agnostic.  in_shardings covers only the dynamic args.
-    base_prefill = make_paged_suffix_prefill(cfg)
+    base_prefill = on_kernel_mesh(make_paged_suffix_prefill(cfg), mesh)
 
     def _prefill_pos(*args):
         return base_prefill(*args[:-1], bucket=args[-1])
@@ -542,7 +564,7 @@ def make_sharded_paged_entry_points(
             if n not in PAGE_POOL_LEAVES and n != "quant_step"
         }
         out["spec_round"] = jax.jit(
-            make_paged_spec_round(cfg, speculate_k),
+            on_kernel_mesh(make_paged_spec_round(cfg, speculate_k), mesh),
             donate_argnums=(1,),
             in_shardings=(params_sh, cache_sh, mat_sh, vec_sh, mat_sh, vec_sh),
             out_shardings=(cache_sh, mat_sh, mat_sh, mat_sh, mat_sh, stacked_sh),

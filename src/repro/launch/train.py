@@ -24,6 +24,7 @@ from repro.core.analog import AnalogConfig
 from repro.core.physics import DeviceParams, calibrate_v_read
 from repro.data import lm_batch, mnist_batch
 from repro.launch import sharding as SH
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim import AdamWConfig
 from repro.train import TrainConfig, init_train_state, make_train_step
@@ -50,6 +51,7 @@ def main() -> None:
                     help="model-parallel size on the host mesh")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.analog:

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro import parallel
 from repro.core import analog as A
+from repro.kernels import ops as KOPS
 from .config import ModelConfig
 from .layers import apply_rope, dtype_of, softcap
 
@@ -509,8 +510,6 @@ def paged_prefill_self_attention(
     kb = _chunk_to_blocks(k, bs)   # (nbc, bs, Hkv, Dh)
     vb = _chunk_to_blocks(v, bs)
     if int8_pool:
-        from repro.kernels import ops as KOPS
-
         kc, ks, vc, vs = [], [], [], []
         for i in range(kb.shape[0]):
             # per-block quantization under content-derived seeds: any
@@ -534,9 +533,7 @@ def paged_prefill_self_attention(
     else:
         k_pages = paged_write_chunk(k_pages, kb, table_row, b0)
         v_pages = paged_write_chunk(v_pages, vb, table_row, b0)
-    if jax.default_backend() == "tpu" or int8_pool:
-        from repro.kernels import ops as KOPS
-
+    if KOPS.tpu_kernels() or int8_pool:
         out = KOPS.paged_prefill_attention(
             q[0], k_pages, v_pages, table_row, q0,
             kind=kind,
@@ -612,8 +609,6 @@ def paged_decode_self_attention(
         # bits) and lets the caller drop the pool from its scan carry.
         pass
     elif int8_pool:
-        from repro.kernels import ops as KOPS
-
         k8, ks, v8, vs = KOPS.quantize_kv_pair_int8(k, v, quant_seed)
         k_pages = paged_write(k_pages, k8, table, pos)
         v_pages = paged_write(v_pages, v8, table, pos)
@@ -622,9 +617,7 @@ def paged_decode_self_attention(
     else:
         k_pages = paged_write(k_pages, k, table, pos)
         v_pages = paged_write(v_pages, v, table, pos)
-    if jax.default_backend() == "tpu":
-        from repro.kernels import ops as KOPS
-
+    if KOPS.tpu_kernels():
         out = KOPS.paged_attention(
             q[:, 0], k_pages, v_pages, table, pos,
             kind=kind,

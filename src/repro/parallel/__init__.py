@@ -66,3 +66,27 @@ def sharding_for(names: Sequence[Optional[str]]) -> Optional[NamedSharding]:
         return None
     mesh, _ = ctx
     return NamedSharding(mesh, spec_for(names))
+
+
+_KERNEL_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_kernel_mesh", default=None
+)
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh: Mesh):
+    """Install the serving mesh for the Pallas kernels traced inside.
+
+    GSPMD cannot partition a Mosaic kernel, so under a mesh every kernel
+    call runs inside ``shard_map`` (kernels/ops.py).  Unlike
+    :func:`axis_rules` this constrains no activation: the rest of the
+    program is partitioned from its argument shardings alone."""
+    token = _KERNEL_MESH.set(mesh)
+    try:
+        yield
+    finally:
+        _KERNEL_MESH.reset(token)
+
+
+def current_kernel_mesh() -> Optional[Mesh]:
+    return _KERNEL_MESH.get()

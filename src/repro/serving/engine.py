@@ -196,7 +196,9 @@ class ServeConfig:
     # and the content-hash prefix index stay host-global, so prefix
     # sharing and COW work across shards unchanged.  Non-divisible dims
     # replicate (divisibility guards).  A 1×1 mesh is byte-identical to
-    # mesh=None (tests/test_serving.py pins it).
+    # mesh=None (tests/test_serving.py pins it).  On TPU only data=1 is
+    # served: the Pallas kernels run per chip over its KV heads and
+    # cannot read pages another chip holds (validate refuses data > 1).
     mesh: Optional[Any] = None
     # paged layout only: self-speculative decoding depth.  k > 0 turns
     # each decode tick into ONE fused draft-k → verify-k device round:
@@ -368,6 +370,18 @@ class ServeConfig:
                 raise ValueError(
                     f"serving mesh needs ('data', 'model') axes, got "
                     f"{sorted(names)}"
+                )
+            data = dict(
+                zip(self.mesh.axis_names, self.mesh.devices.shape)
+            )["data"]
+            if data > 1 and KOPS.tpu_kernels():
+                # the pool's page axis shards over data and any slot may
+                # reference any page, but each chip's kernel reads only
+                # the pages it holds: cross-chip page reads are not built
+                raise ValueError(
+                    f"a serving mesh with data={data} > 1 needs paged "
+                    "kernels that read pages held by other chips; on TPU "
+                    "only the model axis is served (use data=1)"
                 )
         if self.speculate_k < 0:
             raise ValueError(
@@ -693,7 +707,7 @@ class ServingEngine:
         if self.mesh is not None:
             sh = self._shardings
             return jax.jit(
-                fn,
+                SP.on_kernel_mesh(fn, self.mesh),
                 donate_argnums=(1,),
                 in_shardings=(
                     sh["params"], sh["cache"], sh["table"],

@@ -16,6 +16,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config, get_smoke_config
 from repro.launch import sharding as SH
 from repro.launch import roofline as RL
+from repro.launch.mesh import auto_mesh
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -60,7 +61,7 @@ def test_batch_axes_adapt_to_batch_size():
 
 
 def test_param_rules_shard_big_models_fsdp():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     cfg = get_config("nemotron-4-340b")  # > FSDP threshold
     # stacked scanned-unit param: (n_units, D, F)
     sds = jax.ShapeDtypeStruct((96, 18432, 73728), jnp.bfloat16)
@@ -85,7 +86,8 @@ def test_small_mesh_lowering_subprocess():
         from repro.configs.shapes import ShapeSpec
 
         cfg = get_smoke_config("gemma2-2b")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((4, 2), ("data", "model"))
         shape = ShapeSpec("t", 32, 8, "train")
         rules = SH.activation_rules(mesh, cfg, 8)
         with parallel.axis_rules(mesh, rules):
@@ -142,7 +144,8 @@ def test_elastic_checkpoint_reshard_subprocess(tmp_path):
 
         cfg = get_smoke_config("stablelm-3b")
         tcfg = TrainConfig()
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((4, 2), ("data", "model"))
         like = jax.eval_shape(
             lambda: init_train_state(jax.random.PRNGKey(0), cfg, tcfg))
         sh = SH.state_shardings(like, mesh, cfg)
@@ -331,3 +334,60 @@ def test_sharded_engine_token_identity_subprocess():
     assert "DATA_MESH_OK True" in out
     assert "GRID_MESH_OK True" in out
     assert "CAPACITY_OK True" in out
+
+
+def test_kv_head_sharded_kernels_match_unsharded_subprocess():
+    """The paged kernels wrapped in shard_map over a (1, 4) mesh's model
+    axis (each device attends with 2 of 8 KV heads, GQA groups of 2)
+    give the unsharded kernel's readout, in interpret mode: decode and
+    chunked prefill, native and int8 pages."""
+    out = _run_sub("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import functools
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.kernels import ops
+        from repro.kernels.paged_attention import paged_attention_pallas
+        from repro.kernels.prefill_attention import (
+            paged_prefill_attention_pallas)
+        from repro.launch.mesh import make_host_mesh
+
+        mesh = make_host_mesh(model=4, data=1)
+        h, hkv, dh, n_pages, bs, w = 16, 8, 16, 12, 8, 4
+        ks = jax.random.split(jax.random.PRNGKey(0), 6)
+        kp = jax.random.normal(ks[0], (n_pages, bs, hkv, dh))
+        vp = jax.random.normal(ks[1], (n_pages, bs, hkv, dh))
+        kp8 = (kp * 40).astype(jnp.int8)
+        vp8 = (vp * 40).astype(jnp.int8)
+        scales = dict(
+            k_scale=jnp.abs(jax.random.normal(ks[2], kp.shape[:3])) + 0.1,
+            v_scale=jnp.abs(jax.random.normal(ks[3], kp.shape[:3])) + 0.1)
+        pages = NamedSharding(mesh, P(None, None, "model", None))
+        scale_sh = NamedSharding(mesh, P(None, None, "model"))
+        table = jnp.asarray([[3, 7, 1, 9], [2, 11, 5, 4]], jnp.int32)
+        pos = jnp.asarray([29, 13], jnp.int32)
+        q_dec = jax.random.normal(ks[4], (2, h, dh))
+        q_pre = jax.random.normal(ks[5], (11, h, dh))
+        cases = {
+            "decode": (paged_attention_pallas, q_dec, table, pos),
+            "prefill": (paged_prefill_attention_pallas, q_pre, table[0],
+                        jnp.asarray(16, jnp.int32)),
+        }
+        for name, (kernel, q, t, idx) in cases.items():
+            for tag, (k, v, sc) in {
+                "native": (kp, vp, {}), "int8": (kp8, vp8, scales),
+            }.items():
+                want = kernel(q, k, v, t, idx, interpret=True, **sc)
+                sharded = ops.shard_over_kv_heads(kernel, mesh)
+                sc_dev = {n: jax.device_put(a, scale_sh)
+                          for n, a in sc.items()}
+                got = jax.jit(functools.partial(sharded, interpret=True))(
+                    q, jax.device_put(k, pages), jax.device_put(v, pages),
+                    t, idx, **sc_dev)
+                print("MATCH", name, tag,
+                      bool(np.array_equal(np.asarray(got), np.asarray(want))))
+    """)
+    for name in ("decode", "prefill"):
+        for tag in ("native", "int8"):
+            assert f"MATCH {name} {tag} True" in out, out

@@ -355,6 +355,39 @@ def test_prefill_attention_kernel_matches_oracle_int8():
     )
 
 
+@pytest.mark.parametrize("kind,local_window", [("global", 0), ("local", 11)])
+@pytest.mark.parametrize("int8", [False, True])
+def test_prefill_attention_kernel_tiles_queries(kind, local_window, int8):
+    """A chunk longer than the query tile runs as several tiles (128 rows
+    and a ragged 22 here) over GQA groups of 2: each tile restarts the
+    online softmax and must agree with the oracle."""
+    from repro.kernels.prefill_attention import (
+        DEF_TQ, paged_prefill_attention_pallas,
+    )
+
+    s, bs, w = DEF_TQ + 22, 8, 20
+    q, kp, vp, table = _suffix_case(11, s, 4, 2, 16, 24, bs, w)
+    sc = {}
+    if int8:
+        kp = (kp * 40).astype(jnp.int8)
+        vp = (vp * 40).astype(jnp.int8)
+        sc = dict(
+            k_scale=jnp.abs(kp[..., 0].astype(jnp.float32)) / 40 + 0.1,
+            v_scale=jnp.abs(vp[..., 0].astype(jnp.float32)) / 40 + 0.1,
+        )
+    q0 = jnp.asarray(8, jnp.int32)
+    y_ref = ops.ref.prefill_attention_ref(
+        q, kp, vp, table, q0, kind=kind, local_window=local_window, **sc
+    )
+    y_k = paged_prefill_attention_pallas(
+        q, kp, vp, table, q0, kind=kind, local_window=local_window,
+        interpret=True, **sc,
+    )
+    np.testing.assert_allclose(
+        np.asarray(y_k), np.asarray(y_ref), atol=2e-5, rtol=1e-5
+    )
+
+
 def test_prefill_attention_suffix_matches_full_restriction():
     """Per-query independence — the property suffix-only prefill rests
     on: computing only the suffix's queries must give exactly the same

@@ -1211,6 +1211,29 @@ def test_mesh_validation_is_loud(smoke):
         )
 
 
+def test_data_axis_mesh_refused_with_tpu_kernels(monkeypatch):
+    """With the Pallas kernels selected (as on TPU), a mesh whose data
+    axis is > 1 is refused at validation: each chip's kernel reads only
+    the pages that chip holds.  A data axis of 1 passes, and so does
+    data > 1 where the jnp oracles stand in for the kernels."""
+    from repro.kernels import ops
+
+    class Mesh:  # validate reads only the axis names and sizes
+        axis_names = ("data", "model")
+
+        def __init__(self, data, model):
+            self.devices = np.empty((data, model))
+
+    cfg = lambda mesh: ServeConfig(
+        max_batch=2, max_new_tokens=2, max_len=32, mesh=mesh
+    )
+    cfg(Mesh(2, 2)).validate()            # CPU: the oracles read any page
+    monkeypatch.setattr(ops, "tpu_kernels", lambda: True)
+    cfg(Mesh(1, 4)).validate()
+    with pytest.raises(ValueError, match="data=2 > 1"):
+        cfg(Mesh(2, 2)).validate()
+
+
 @pytest.mark.parametrize("arch", ["stablelm-3b", "recurrentgemma-2b"])
 def test_sharded_1x1_mesh_byte_identity(arch):
     """The sharded-decode acceptance contract: an engine on a 1×1
