@@ -174,9 +174,12 @@ def paged_attention_pallas(
         table.astype(jnp.int32),
         pos.astype(jnp.int32),
         q.astype(jnp.float32),
-        k_pages if int8 else k_pages.astype(jnp.float32),
-        v_pages if int8 else v_pages.astype(jnp.float32),
     ]
+    with jax.named_scope("engine.pool_cast"):
+        operands += [
+            k_pages if int8 else k_pages.astype(jnp.float32),
+            v_pages if int8 else v_pages.astype(jnp.float32),
+        ]
     if int8:
         in_specs += [
             pl.BlockSpec((1, bs, hkv), scale_map),

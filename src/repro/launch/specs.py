@@ -605,50 +605,53 @@ def sample_tokens(
     reads on a fold of the slot key by the read index) and the published
     token is the majority vote over the R reads (ties break to the lowest
     token id).  ``R = 1`` is byte-identical to the pre-knob trace.
+
+    Its operations carry the device scope ``engine.sample``.
     """
-    if not (cfg.wta_head and key is not None):
-        return jnp.argmax(logits, axis=-1).astype(_i32)
+    with jax.named_scope("engine.sample"):
+        if not (cfg.wta_head and key is not None):
+            return jnp.argmax(logits, axis=-1).astype(_i32)
 
-    from repro.core import wta as W
-    from repro.kernels import backend as BK
+        from repro.core import wta as W
+        from repro.kernels import backend as BK
 
-    vth0, sigma_z = BK.get_backend().wta_readout_params(
-        cfg.analog.vth0, W.wta_sigma_z(cfg.analog.beta)
-    )
-
-    def counts_one(k, z):
-        res = W.wta_trials(
-            k,
-            z.astype(jnp.float32),
-            n_trials=cfg.analog.wta_trials,
-            vth0=vth0,
-            sigma_z=sigma_z,
+        vth0, sigma_z = BK.get_backend().wta_readout_params(
+            cfg.analog.vth0, W.wta_sigma_z(cfg.analog.beta)
         )
-        return res.counts
 
-    def sample_once(k):
-        if k.ndim == 2:  # per-slot keys
-            if steps is not None:
-                k = jax.vmap(jax.random.fold_in)(k, steps)
-            counts = jax.vmap(counts_one)(k, logits)
-        else:
-            counts = counts_one(k, logits)
-        return jnp.argmax(counts, axis=-1).astype(_i32)
+        def counts_one(k, z):
+            res = W.wta_trials(
+                k,
+                z.astype(jnp.float32),
+                n_trials=cfg.analog.wta_trials,
+                vth0=vth0,
+                sigma_z=sigma_z,
+            )
+            return res.counts
 
-    reads = max(int(n_redundant), 1)
-    if reads == 1:
-        return sample_once(key)
-    votes = [sample_once(key)]
-    for r in range(1, reads):
-        if key.ndim == 2:
-            kr = jax.vmap(jax.random.fold_in, in_axes=(0, None))(key, r)
-        else:
-            kr = jax.random.fold_in(key, r)
-        votes.append(sample_once(kr))
-    tally = jax.nn.one_hot(
-        jnp.stack(votes, axis=0), logits.shape[-1], dtype=_i32
-    ).sum(axis=0)
-    return jnp.argmax(tally, axis=-1).astype(_i32)
+        def sample_once(k):
+            if k.ndim == 2:  # per-slot keys
+                if steps is not None:
+                    k = jax.vmap(jax.random.fold_in)(k, steps)
+                counts = jax.vmap(counts_one)(k, logits)
+            else:
+                counts = counts_one(k, logits)
+            return jnp.argmax(counts, axis=-1).astype(_i32)
+
+        reads = max(int(n_redundant), 1)
+        if reads == 1:
+            return sample_once(key)
+        votes = [sample_once(key)]
+        for r in range(1, reads):
+            if key.ndim == 2:
+                kr = jax.vmap(jax.random.fold_in, in_axes=(0, None))(key, r)
+            else:
+                kr = jax.random.fold_in(key, r)
+            votes.append(sample_once(kr))
+        tally = jax.nn.one_hot(
+            jnp.stack(votes, axis=0), logits.shape[-1], dtype=_i32
+        ).sum(axis=0)
+        return jnp.argmax(tally, axis=-1).astype(_i32)
 
 
 def analog_call_profile(

@@ -507,32 +507,33 @@ def paged_prefill_self_attention(
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
     b0 = q0 // bs
-    kb = _chunk_to_blocks(k, bs)   # (nbc, bs, Hkv, Dh)
-    vb = _chunk_to_blocks(v, bs)
-    if int8_pool:
-        kc, ks, vc, vs = [], [], [], []
-        for i in range(kb.shape[0]):
-            # per-block quantization under content-derived seeds: any
-            # writer of the same block content produces bit-identical
-            # codes, which is what keeps int8 blocks shareable
-            k8, ksc, v8, vsc = KOPS.quantize_kv_pair_int8(
-                kb[i], vb[i], quant_seeds[i]
+    with jax.named_scope("engine.kv_write"):
+        kb = _chunk_to_blocks(k, bs)   # (nbc, bs, Hkv, Dh)
+        vb = _chunk_to_blocks(v, bs)
+        if int8_pool:
+            kc, ks, vc, vs = [], [], [], []
+            for i in range(kb.shape[0]):
+                # per-block quantization under content-derived seeds: any
+                # writer of the same block content produces bit-identical
+                # codes, which is what keeps int8 blocks shareable
+                k8, ksc, v8, vsc = KOPS.quantize_kv_pair_int8(
+                    kb[i], vb[i], quant_seeds[i]
+                )
+                kc.append(k8)
+                ks.append(ksc)
+                vc.append(v8)
+                vs.append(vsc)
+            k_pages = paged_write_chunk(k_pages, jnp.stack(kc), table_row, b0)
+            v_pages = paged_write_chunk(v_pages, jnp.stack(vc), table_row, b0)
+            k_scale_pages = paged_write_chunk(
+                k_scale_pages, jnp.stack(ks), table_row, b0
             )
-            kc.append(k8)
-            ks.append(ksc)
-            vc.append(v8)
-            vs.append(vsc)
-        k_pages = paged_write_chunk(k_pages, jnp.stack(kc), table_row, b0)
-        v_pages = paged_write_chunk(v_pages, jnp.stack(vc), table_row, b0)
-        k_scale_pages = paged_write_chunk(
-            k_scale_pages, jnp.stack(ks), table_row, b0
-        )
-        v_scale_pages = paged_write_chunk(
-            v_scale_pages, jnp.stack(vs), table_row, b0
-        )
-    else:
-        k_pages = paged_write_chunk(k_pages, kb, table_row, b0)
-        v_pages = paged_write_chunk(v_pages, vb, table_row, b0)
+            v_scale_pages = paged_write_chunk(
+                v_scale_pages, jnp.stack(vs), table_row, b0
+            )
+        else:
+            k_pages = paged_write_chunk(k_pages, kb, table_row, b0)
+            v_pages = paged_write_chunk(v_pages, vb, table_row, b0)
     if KOPS.tpu_kernels() or int8_pool:
         out = KOPS.paged_prefill_attention(
             q[0], k_pages, v_pages, table_row, q0,
@@ -600,23 +601,23 @@ def paged_decode_self_attention(
     if use_rope:
         q = apply_rope(q, pos[:, None], cfg.rope_theta)
         k = apply_rope(k, pos[:, None], cfg.rope_theta)
-    if not write:
-        # speculative-verify re-read: the draft step already wrote this
-        # position's K/V (bit-identical rows — same inputs, same seed
-        # trajectory), so the verifier attends the pages as they are.
-        # Skipping the write keeps the pool untouched (int8 pools would
-        # otherwise re-quantize under a different quant_step and change
-        # bits) and lets the caller drop the pool from its scan carry.
-        pass
-    elif int8_pool:
-        k8, ks, v8, vs = KOPS.quantize_kv_pair_int8(k, v, quant_seed)
-        k_pages = paged_write(k_pages, k8, table, pos)
-        v_pages = paged_write(v_pages, v8, table, pos)
-        k_scale_pages = paged_write(k_scale_pages, ks, table, pos)
-        v_scale_pages = paged_write(v_scale_pages, vs, table, pos)
-    else:
-        k_pages = paged_write(k_pages, k, table, pos)
-        v_pages = paged_write(v_pages, v, table, pos)
+    # ``write=False`` is the speculative-verify re-read: the draft step
+    # already wrote this position's K/V (bit-identical rows — same inputs,
+    # same seed trajectory), so the verifier attends the pages as they
+    # are.  Skipping the write keeps the pool untouched (int8 pools would
+    # otherwise re-quantize under a different quant_step and change bits)
+    # and lets the caller drop the pool from its scan carry.
+    if write:
+        with jax.named_scope("engine.kv_write"):
+            if int8_pool:
+                k8, ks, v8, vs = KOPS.quantize_kv_pair_int8(k, v, quant_seed)
+                k_pages = paged_write(k_pages, k8, table, pos)
+                v_pages = paged_write(v_pages, v8, table, pos)
+                k_scale_pages = paged_write(k_scale_pages, ks, table, pos)
+                v_scale_pages = paged_write(v_scale_pages, vs, table, pos)
+            else:
+                k_pages = paged_write(k_pages, k, table, pos)
+                v_pages = paged_write(v_pages, v, table, pos)
     if KOPS.tpu_kernels():
         out = KOPS.paged_attention(
             q[:, 0], k_pages, v_pages, table, pos,

@@ -474,7 +474,6 @@ class ServingMetrics:
     wall_time: float = 0.0
     tokens_per_s: float = 0.0
     ttft_mean: float = 0.0      # submit → first generated token, seconds
-    ttft_max: float = 0.0
     decode_steps: int = 0
     prefills: int = 0            # bucket prefills actually COMPUTED
     occupancy_mean: float = 0.0  # mean busy-slot fraction per decode step
@@ -486,6 +485,8 @@ class ServingMetrics:
     prefill_tokens_saved: int = 0  # prompt tokens skipped via the index
     ttft_p50: float = 0.0         # TTFT percentiles over completed requests
     ttft_p99: float = 0.0
+    admissions: int = 0           # requests seated in a slot (restores too)
+    queue_wait_s: float = 0.0     # summed queue → slot wait of admissions
     preemptions: int = 0          # spill-to-host preemptions
     restores: int = 0             # spilled requests re-admitted
     spill_drops: int = 0          # spill records dropped by the bytes budget
@@ -662,6 +663,8 @@ class ServingEngine:
         # the dense layout)
         self._replay: dict[int, list[int]] = {}
         self._ticks = 0
+        self._admissions = 0
+        self._queue_wait = 0.0
         self._preemptions = 0
         self._restores = 0
         self._spill_drops = 0
@@ -1137,12 +1140,15 @@ class ServingEngine:
         the chunk steps host-side) until the job completes, so the batched
         decode steps running for the OTHER slots in the meantime can never
         corrupt a prefill in flight."""
+        self._admissions += 1
+        self._queue_wait += time.perf_counter() - req.queued_since
         slot = req.slot
         plen = self._bucket(len(req.prompt))
         rkey = jax.random.fold_in(self._base_key, req.rid)
         if self._cache is None:
             self._cache = self._init_cache()
-        self._req_keys[slot] = np.asarray(rkey)
+        with jax.profiler.TraceAnnotation("engine.admit.wait"):
+            self._req_keys[slot] = np.asarray(rkey)
         if not self.paged:
             toks = np.asarray([left_pad(req.prompt, plen)], np.int32)
             one_cache, tok0, _ = self._prefill(
@@ -1253,7 +1259,8 @@ class ServingEngine:
                 del self._replay[req.rid]
             self._steps[slot] = 1
             return
-        t0 = int(tok0[0])  # blocks on the prefill — TTFT stamps after it
+        with jax.profiler.TraceAnnotation("engine.prefill.wait"):
+            t0 = int(tok0[0])  # blocks on the prefill — TTFT stamps after it
         self._tokens[slot] = t0
         self._steps[slot] = 1
         self._total_tokens += 1
@@ -1384,7 +1391,7 @@ class ServingEngine:
         })
         self.blocks.free(rid)
         self._table[slot, :] = 0
-        self.sched.requeue(req)
+        self.sched.requeue(req, now=time.perf_counter())
         self._preemptions += 1
 
     def _preempt_pass(self) -> None:
@@ -1412,7 +1419,10 @@ class ServingEngine:
             victim = max(victims, key=lambda r: (r.priority, r.rid))
             self._preempt(victim)
             for req in self.sched.admit(self._try_reserve_blocks):
-                self._admit_one(req)
+                with jax.profiler.TraceAnnotation(
+                    "engine.admit.request", rid=req.rid
+                ):
+                    self._admit_one(req)
 
     def _evict_request(self, req: Request, reason: str, now: float) -> None:
         """Terminally evict a request in ANY live state, atomically.
@@ -1663,19 +1673,22 @@ class ServingEngine:
             c = min((q0 // grid + 1) * grid, bucket) - q0
             bs = self.cfg.kv_block_size
             b0, b1 = q0 // bs, -(-(q0 + c) // bs)
-            args = [
-                self.params,
-                self._cache,
-                job["state"],
-                jnp.asarray([job["tokens"][q0 : q0 + c]], jnp.int32),
-                jnp.asarray(job["row"][: plan["n_prompt"]]),
-                jnp.asarray(q0, jnp.int32),
-            ]
-            if self.int8:
-                args.append(jnp.asarray(plan["seeds"][b0:b1]))
-            self._cache, job["state"], logits = self._suffix_prefill(
-                *args, bucket=bucket
-            )
+            with jax.profiler.TraceAnnotation(
+                "engine.prefill.chunk", rid=rid
+            ):
+                args = [
+                    self.params,
+                    self._cache,
+                    job["state"],
+                    jnp.asarray([job["tokens"][q0 : q0 + c]], jnp.int32),
+                    jnp.asarray(job["row"][: plan["n_prompt"]]),
+                    jnp.asarray(q0, jnp.int32),
+                ]
+                if self.int8:
+                    args.append(jnp.asarray(plan["seeds"][b0:b1]))
+                self._cache, job["state"], logits = self._suffix_prefill(
+                    *args, bucket=bucket
+                )
             self._prefill_tokens += c
             self.backend.note_call(
                 SP.analog_call_profile("suffix_prefill", tokens=c)
@@ -1722,7 +1735,7 @@ class ServingEngine:
             if getattr(self.backend, "overrides_compute", False)
             else contextlib.nullcontext()
         )
-        with ctx:
+        with ctx, jax.profiler.TraceAnnotation("engine.tick"):
             self._tick_dirty = 0
             self._tick_canary = None
             try:
@@ -1741,24 +1754,30 @@ class ServingEngine:
         # deadline pass: expired requests evict in whatever state they
         # are — queued, mid-chunked-prefill (job + pages dropped
         # atomically), or decoding
-        expired = self.sched.expired(time.perf_counter())
-        for req in expired:
-            self._evict_request(req, "deadline", time.perf_counter())
-        gate = self._try_reserve_blocks if self.paged else None
-        pol = self.cfg.degradation
-        shed = (
-            pol.shed_priority_above
-            if pol is not None and self._degrade_level >= 3
-            else None
-        )
-        for req in self.sched.admit(gate, shed_priority_above=shed):
-            self._admit_one(req)
-            if not self.paged:
-                emitted.append((req.rid, req.output[-1]))
+        with jax.profiler.TraceAnnotation("engine.admit"):
+            expired = self.sched.expired(time.perf_counter())
+            for req in expired:
+                self._evict_request(req, "deadline", time.perf_counter())
+            gate = self._try_reserve_blocks if self.paged else None
+            pol = self.cfg.degradation
+            shed = (
+                pol.shed_priority_above
+                if pol is not None and self._degrade_level >= 3
+                else None
+            )
+            for req in self.sched.admit(gate, shed_priority_above=shed):
+                with jax.profiler.TraceAnnotation(
+                    "engine.admit.request", rid=req.rid
+                ):
+                    self._admit_one(req)
+                if not self.paged:
+                    emitted.append((req.rid, req.output[-1]))
         if self.paged and self.cfg.enable_preemption:
-            self._preempt_pass()
+            with jax.profiler.TraceAnnotation("engine.preempt"):
+                self._preempt_pass()
         if self.paged:
-            self._prefill_tick(emitted)
+            with jax.profiler.TraceAnnotation("engine.prefill"):
+                self._prefill_tick(emitted)
         active = self.sched.active()
         # speculate only when every draft write stays inside max_len —
         # near-capacity tails fall back to plain single-token ticks, so
@@ -1770,83 +1789,90 @@ class ServingEngine:
             and self._degrade_level < 1
         )
         if active and self.sharing:
-            self._cow_pass(active, self.spec_k if spec_now else 1)
+            with jax.profiler.TraceAnnotation("engine.cow"):
+                self._cow_pass(active, self.spec_k if spec_now else 1)
         if active:
             t_dec = time.perf_counter()
             if spec_now:
-                self._spec_tick(active, emitted)
+                with jax.profiler.TraceAnnotation("engine.spec"):
+                    self._spec_tick(active, emitted)
                 self._decode_time += time.perf_counter() - t_dec
                 self._busy_time += time.perf_counter() - t_start
                 return emitted
             sane_np = None
-            if self.paged:
-                w = self._window_blocks(active)
-                r_eff = self._redundant_effective()
-                self._cache, nxt, sane = self._get_serve_step(r_eff)(
-                    self.params,
-                    self._cache,
-                    self._put(self._table[:, :w], "table"),
-                    self._put(self._tokens, "slot_vec"),
-                    self._put(self._req_keys, "slot_keys"),
-                    self._put(self._steps, "slot_vec"),
-                )
-                sane_np = np.asarray(sane)
-                self._host_pos += 1  # mirrors the step's pos+1, every slot
-            else:
-                r_eff = 1
-                self._cache, nxt = self._serve_step(
-                    self.params,
-                    self._cache,
-                    jnp.asarray(self._tokens),
-                    jnp.asarray(self._req_keys),
-                    jnp.asarray(self._steps),
-                )
-            nxt_np = np.asarray(nxt)  # device sync — decode_time is honest
-            # logical decode work this step: one forward + one sampling
-            # decision per ACTIVE slot (idle-slot padding is not logical
-            # work — counting it would break batch-composition
-            # invariance); redundant comparator re-reads beyond the first
-            # are priced per active slot the same way
-            self.backend.note_call(
-                SP.analog_call_profile(
-                    "serve_step", batch=len(active),
-                    redundant=(r_eff - 1) * len(active),
-                )
-            )
-            now = time.perf_counter()
-            self._decode_time += now - t_dec
-            self._occ_sum += len(active) / self.cfg.max_batch
-            self._decode_steps += 1
-            for req in active:
-                slot = req.slot
-                if sane_np is not None and int(sane_np[slot]):
-                    # logit-sanity trip (analog garbage / injected
-                    # fault): evict with the matching typed reason
-                    # instead of publishing a garbage token — the slot
-                    # frees, serving continues
-                    self._evict_request(
-                        req,
-                        SP.SANITY_REASONS.get(int(sane_np[slot]), "nan"),
-                        now,
+            with jax.profiler.TraceAnnotation("engine.decode.dispatch"):
+                if self.paged:
+                    w = self._window_blocks(active)
+                    r_eff = self._redundant_effective()
+                    self._cache, nxt, sane = self._get_serve_step(r_eff)(
+                        self.params,
+                        self._cache,
+                        self._put(self._table[:, :w], "table"),
+                        self._put(self._tokens, "slot_vec"),
+                        self._put(self._req_keys, "slot_keys"),
+                        self._put(self._steps, "slot_vec"),
                     )
-                    continue
-                t = int(nxt_np[slot])
-                rep = self._replay.get(req.rid)
-                if rep is not None:
-                    # teacher-force the next already-published token (the
-                    # sampled one is bitwise the same in a fault-free
-                    # run); nothing re-records or re-publishes
-                    self._tokens[slot] = rep.pop(0)
-                    if not rep:
-                        del self._replay[req.rid]
+                else:
+                    r_eff = 1
+                    self._cache, nxt = self._serve_step(
+                        self.params,
+                        self._cache,
+                        jnp.asarray(self._tokens),
+                        jnp.asarray(self._req_keys),
+                        jnp.asarray(self._steps),
+                    )
+            with jax.profiler.TraceAnnotation("engine.decode.wait"):
+                if self.paged:
+                    sane_np = np.asarray(sane)
+                nxt_np = np.asarray(nxt)  # device sync — decode_time is honest
+            if self.paged:
+                self._host_pos += 1  # mirrors the step's pos+1, every slot
+            with jax.profiler.TraceAnnotation("engine.emit"):
+                # logical decode work this step: one forward + one sampling
+                # decision per ACTIVE slot (idle-slot padding is not logical
+                # work — counting it would break batch-composition
+                # invariance); redundant comparator re-reads beyond the first
+                # are priced per active slot the same way
+                self.backend.note_call(
+                    SP.analog_call_profile(
+                        "serve_step", batch=len(active),
+                        redundant=(r_eff - 1) * len(active),
+                    )
+                )
+                now = time.perf_counter()
+                self._decode_time += now - t_dec
+                self._occ_sum += len(active) / self.cfg.max_batch
+                self._decode_steps += 1
+                for req in active:
+                    slot = req.slot
+                    if sane_np is not None and int(sane_np[slot]):
+                        # logit-sanity trip (analog garbage / injected
+                        # fault): evict with the matching typed reason
+                        # instead of publishing a garbage token — the slot
+                        # frees, serving continues
+                        self._evict_request(
+                            req,
+                            SP.SANITY_REASONS.get(int(sane_np[slot]), "nan"),
+                            now,
+                        )
+                        continue
+                    t = int(nxt_np[slot])
+                    rep = self._replay.get(req.rid)
+                    if rep is not None:
+                        # teacher-force the next already-published token (the
+                        # sampled one is bitwise the same in a fault-free
+                        # run); nothing re-records or re-publishes
+                        self._tokens[slot] = rep.pop(0)
+                        if not rep:
+                            del self._replay[req.rid]
+                        self._steps[slot] += 1
+                        continue
+                    self._tokens[slot] = t
                     self._steps[slot] += 1
-                    continue
-                self._tokens[slot] = t
-                self._steps[slot] += 1
-                self._total_tokens += 1
-                self.sched.record_token(req, t, self.cfg.eos_token, now)
-                self._release_if_done(req)
-                emitted.append((req.rid, t))
+                    self._total_tokens += 1
+                    self.sched.record_token(req, t, self.cfg.eos_token, now)
+                    self._release_if_done(req)
+                    emitted.append((req.rid, t))
         self._busy_time += time.perf_counter() - t_start
         return emitted
 
@@ -2185,7 +2211,6 @@ class ServingEngine:
             wall_time=wall,
             tokens_per_s=self._total_tokens / max(wall, 1e-9),
             ttft_mean=float(np.mean(ttfts)) if ttfts else 0.0,
-            ttft_max=float(np.max(ttfts)) if ttfts else 0.0,
             decode_steps=self._decode_steps,
             prefills=self._prefills,
             occupancy_mean=self._occ_sum / max(self._decode_steps, 1),
@@ -2197,6 +2222,8 @@ class ServingEngine:
             prefill_tokens_saved=self._prefill_tokens_saved,
             ttft_p50=_pctl(ttfts, 50),
             ttft_p99=_pctl(ttfts, 99),
+            admissions=self._admissions,
+            queue_wait_s=self._queue_wait,
             preemptions=self._preemptions,
             restores=self._restores,
             spill_drops=self._spill_drops,
@@ -2390,7 +2417,6 @@ class StaticServingEngine:
             wall_time=wall,
             tokens_per_s=self._total_tokens / max(wall, 1e-9),
             ttft_mean=float(np.mean(self._ttfts)) if self._ttfts else 0.0,
-            ttft_max=float(np.max(self._ttfts)) if self._ttfts else 0.0,
             decode_steps=self._decode_steps,
             prefills=self._completed,
             occupancy_mean=self._occ_sum / max(self._decode_steps, 1),

@@ -84,6 +84,9 @@ class Request:
     # admitted into the same slot.
     done_slot: Optional[int] = None
     submit_time: float = 0.0
+    # when the request last entered the queue: at submit, and again at a
+    # requeue after preemption (the engine's queue-wait counter)
+    queued_since: float = 0.0
     first_token_time: Optional[float] = None
     done_time: Optional[float] = None
     # scheduling class: lower is more urgent (PRIORITY_INTERACTIVE beats
@@ -407,6 +410,7 @@ class Scheduler:
             prompt=list(prompt),
             max_new_tokens=int(max_new_tokens),
             submit_time=now,
+            queued_since=now,
             priority=int(priority),
             deadline_ms=None if deadline_ms is None else float(deadline_ms),
         )
@@ -510,9 +514,9 @@ class Scheduler:
         req.done_slot = req.slot
         req.slot = None
 
-    def requeue(self, req: Request) -> None:
+    def requeue(self, req: Request, now: float = 0.0) -> None:
         """Preempt a slotted request back to QUEUED (slot freed, output and
-        timing kept).
+        timing kept; ``now`` restarts its queue wait).
 
         The rid is unchanged, so the priority queue puts the request back
         at the head of its class — a preempted request is never overtaken
@@ -525,6 +529,7 @@ class Scheduler:
         self._slots[req.slot] = None
         req.slot = None
         req.state = RequestState.QUEUED
+        req.queued_since = now
         req.preemptions += 1
         self._queue.append(req)
 

@@ -1310,6 +1310,31 @@ def test_requeue_roundtrip():
     assert req2 is a and a.slot == 0
 
 
+def test_requeue_restarts_the_queue_wait():
+    s = Scheduler(n_slots=1)
+    a = s.submit([1, 2], 4, now=1.0)
+    assert a.queued_since == 1.0
+    (req,) = s.admit()
+    s.start_decode(req)
+    s.requeue(req, now=5.0)
+    assert a.queued_since == 5.0 and a.submit_time == 1.0
+
+
+def test_queue_wait_counts_each_admission_from_submit_to_its_slot(smoke):
+    """One slot, two requests: the second waits in the queue until the
+    first is done; each seat comes before its first token."""
+    cfg, params = smoke
+    eng = ServingEngine(params, cfg, ServeConfig(
+        max_batch=1, max_new_tokens=4, max_len=64, kv_block_size=8))
+    a, b = (eng.sched.request(eng.submit(p, 4)) for p in ([1, 2], [3, 4]))
+    eng.run()
+    m = eng.metrics()
+    assert m.admissions == 2
+    assert m.queue_wait_s >= a.done_time - b.submit_time
+    assert m.queue_wait_s <= (a.first_token_time - a.submit_time) + (
+        b.first_token_time - b.submit_time)
+
+
 def test_cancel_and_expired():
     s = Scheduler(n_slots=1)
     a = s.submit([1], 4, now=0.0, deadline_ms=50.0)

@@ -6,13 +6,16 @@ stablelm-3b widths (32 heads of 80, 16-token pages, a pool of 257 pages:
 topology — the TPU compiler is installed, the chip is not.  A compile
 refuses what interpret mode accepts: tiles not aligned to the layout,
 more VMEM than a kernel may use, unsupported casts.  Nothing runs, so
-nothing here says anything about results or times.
+nothing here says anything about results or times.  The serve step's
+compile also guards the device scopes that name its work in the trace.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and every test worker imports every test file.
 """
 
+import dataclasses
+import re
 import time
 
 import jax
@@ -61,7 +64,7 @@ def _compile(fn, *shapes):
     secs = time.perf_counter() - t0
     assert "tpu_custom_call" in compiled.as_text()
     assert secs < COMPILE_S, f"compile took {secs:.1f}s"
-    return compiled.memory_analysis()
+    return compiled
 
 
 def _pool_args(one_chip, dtype):
@@ -94,7 +97,7 @@ def test_paged_decode_kernel_compiles(one_chip, dtype):
     ma = _compile(
         f, sds((b, H, DH), jnp.float32), *pages,
         sds((b, w), jnp.int32), sds((b,), jnp.int32), *scales,
-    )
+    ).memory_analysis()
     assert ma.temp_size_in_bytes <= _temp_bound(dtype)
 
 
@@ -120,7 +123,7 @@ def test_chunked_prefill_kernel_compiles(one_chip, dtype, chunk):
     ma = _compile(
         f, sds((chunk, H, DH), jnp.float32), *pages,
         sds((1024 // BS,), jnp.int32), sds((), jnp.int32), *scales,
-    )
+    ).memory_analysis()
     assert ma.temp_size_in_bytes <= _temp_bound(dtype)
 
 
@@ -135,5 +138,39 @@ def test_stoch_round_kernel_compiles(one_chip):
             x, seed, step=1.0, lo=-127.0, hi=127.0
         ),
         sds((BS * HKV, 512), jnp.float32), sds((1,), jnp.int32),
-    )
+    ).memory_analysis()
     assert ma.temp_size_in_bytes <= 4 << 20
+
+
+@pytest.mark.parametrize("kv,wta", [("same", False), ("int8", True)])
+def test_serve_step_names_its_device_scopes(one_chip, monkeypatch, kv, wta):
+    """The paged serve step at stablelm-3b widths (2 layers), as the chip
+    runs it: its optimised HLO keeps the scopes the trace reads in each
+    op's ``op_name`` -- the sampler, the KV writes, and the f32 cast of
+    the pool in front of the kernel, which int8 pages skip."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.launch import specs as SP
+
+    monkeypatch.setattr(ops, "tpu_kernels", lambda: True)
+    mc = dataclasses.replace(
+        get_config("stablelm-3b"), n_layers=2, kv_cache_dtype=kv,
+        wta_head=wta,
+    )
+    on = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree,
+    )
+    b, w = 4, 1024 // BS
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    text = _compile(
+        SP.make_paged_serve_step(mc),
+        on(SP.params_specs(mc)),
+        on(SP.paged_decode_cache_specs(mc, b, PAGES, BS)),
+        sds((b, w), jnp.int32), sds((b,), jnp.int32),
+        sds((b, 2), jnp.uint32), sds((b,), jnp.int32),
+    ).as_text()
+    op_names = " ".join(re.findall(r'op_name="([^"]*)"', text))
+    assert "engine.sample/" in op_names
+    assert "engine.kv_write/" in op_names
+    assert ("engine.pool_cast/" in op_names) == (kv != "int8")
