@@ -459,7 +459,7 @@ def _on_kernel_mesh(kernel):
 
 def paged_attention(
     q: jax.Array,        # (B, H, Dh)
-    k_pages: jax.Array,  # (P, bs, Hkv, Dh) — cache dtype or int8 codes
+    k_pages: jax.Array,  # (P, bs, Hkv, Dp≥Dh) — cache dtype or int8 codes
     v_pages: jax.Array,
     table: jax.Array,    # (B, W) int32
     pos: jax.Array,      # (B,) int32
@@ -481,7 +481,7 @@ def paged_attention(
 
 def paged_attention_sim(
     q: jax.Array,        # (B, H, Dh)
-    k_pages: jax.Array,  # (P, bs, Hkv, Dh) — cache dtype or int8 codes
+    k_pages: jax.Array,  # (P, bs, Hkv, Dp≥Dh) — cache dtype or int8 codes
     v_pages: jax.Array,
     table: jax.Array,    # (B, W) int32
     pos: jax.Array,      # (B,) int32
@@ -523,7 +523,7 @@ def paged_attention_sim(
 
 def paged_prefill_attention(
     q: jax.Array,        # (S, H, Dh) — one request's suffix-chunk queries
-    k_pages: jax.Array,  # (P, bs, Hkv, Dh) — cache dtype or int8 codes
+    k_pages: jax.Array,  # (P, bs, Hkv, Dp≥Dh) — cache dtype or int8 codes
     v_pages: jax.Array,
     table: jax.Array,    # (W,) int32 — the request's block-table row
     q0: jax.Array,       # () int32 absolute position of the first query
@@ -546,7 +546,7 @@ def paged_prefill_attention(
 
 def paged_prefill_attention_sim(
     q: jax.Array,        # (S, H, Dh) — one request's suffix-chunk queries
-    k_pages: jax.Array,  # (P, bs, Hkv, Dh) — cache dtype or int8 codes
+    k_pages: jax.Array,  # (P, bs, Hkv, Dp≥Dh) — cache dtype or int8 codes
     v_pages: jax.Array,
     table: jax.Array,    # (W,) int32 — the request's block-table row
     q0: jax.Array,       # () int32 absolute position of the first query

@@ -10,7 +10,17 @@ kernel attends over exactly the blocks a request has filled:
     pages the request never touched are never fetched;
   * the flash-attention recurrence (running max / denom / accumulator)
     lives in VMEM scratch across the sequential block axis;
-  * blocks entirely beyond the request's position are skipped via pl.when.
+  * blocks entirely beyond the request's position are skipped via pl.when;
+  * a page crosses HBM in the pool's own dtype (bf16 pools move bf16) and
+    is upcast to f32 in VMEM, so no f32 copy of the pool ever exists.
+
+The pool is whatever the table's page ids index.  The decode step passes
+every layer's pool at once, as the (units·sublayers·P, bs, Hkv, Dp) view
+of its stack, with this layer's offset already added to the table: the
+kernel reads the layer's pages in place, and nothing slices them out.
+The engine's rows are the head zero-padded to whole 128-lane rows (Dp),
+the width the TPU lays such a pool out row-major for; the kernel DMAs
+whole rows and computes on the head's Dh lanes.
 
 int8 pools halve that HBM traffic again: K/V pages hold int8 codes plus a
 per-(page, slot-in-page, head) f32 scale plane, and dequantization is fused
@@ -43,8 +53,8 @@ def _kernel(
     tbl_ref,   # (B, W) int32 SMEM (scalar prefetch): block table
     pos_ref,   # (B,) int32 SMEM (scalar prefetch): last valid position
     q_ref,     # (1, H, Dh) f32
-    k_ref,     # (1, bs, Hkv, Dh) f32 (or int8 codes) — page tbl[b, w]
-    v_ref,     # (1, bs, Hkv, Dh) f32 (or int8 codes)
+    k_ref,     # (1, bs, Hkv, Dp) pool dtype or int8 — page tbl[b, w]
+    v_ref,     # (1, bs, Hkv, Dp) pool dtype or int8; Dp ≥ Dh
     *rest,     # int8: ks_ref, vs_ref (1, bs, Hkv) f32, then o/m/l/acc refs
     nw: int,
     bs: int,
@@ -79,8 +89,9 @@ def _kernel(
         qg = q.reshape(hkv, g, dh).astype(jnp.float32) * jnp.float32(
             dh**-0.5
         )
-        k = k_ref[0].astype(jnp.float32)   # (bs, Hkv, Dh)
-        v = v_ref[0].astype(jnp.float32)
+        # the head's lanes of the page (rows may be padded past it)
+        k = k_ref[0, :, :, :dh].astype(jnp.float32)   # (bs, Hkv, Dh)
+        v = v_ref[0, :, :, :dh].astype(jnp.float32)
         sc = jnp.einsum(
             "kgd,tkd->kgt", qg, k, preferred_element_type=jnp.float32
         )
@@ -126,7 +137,7 @@ def _kernel(
 
 def paged_attention_pallas(
     q: jax.Array,        # (B, H, Dh) f32 — one query token per slot
-    k_pages: jax.Array,  # (P, bs, Hkv, Dh) f32 (or int8 codes) block pool
+    k_pages: jax.Array,  # (P, bs, Hkv, Dp≥Dh) pool dtype (or int8 codes)
     v_pages: jax.Array,
     table: jax.Array,    # (B, W) int32 page ids; <0 treated as page 0
     pos: jax.Array,      # (B,) int32 last valid key position per slot
@@ -142,10 +153,13 @@ def paged_attention_pallas(
 
     Pass int8 ``k_pages``/``v_pages`` together with ``k_scale``/``v_scale``
     planes to run the fused-dequant path (int8 page DMA, scaling in VMEM).
+    Page rows may be wider than the head (``Dp``, zero-padded to whole
+    lanes as the engine's pools are): a whole row crosses HBM and the
+    kernel computes on the head's ``Dh`` lanes of it.
     """
     b, h, dh = q.shape
-    n_pages, bs, hkv, dh2 = k_pages.shape
-    assert dh == dh2 and h % hkv == 0, (q.shape, k_pages.shape)
+    n_pages, bs, hkv, dp = k_pages.shape
+    assert dh <= dp and h % hkv == 0, (q.shape, k_pages.shape)
     int8 = k_scale is not None
     if int8:
         assert v_scale is not None
@@ -165,21 +179,19 @@ def paged_attention_pallas(
     scale_map = lambda bi, wi, tbl, ps: (jnp.maximum(tbl[bi, wi], 0), 0, 0)
     in_specs = [
         pl.BlockSpec((1, h, dh), lambda bi, wi, tbl, ps: (bi, 0, 0)),
-        pl.BlockSpec((1, bs, hkv, dh), page_map),
-        pl.BlockSpec((1, bs, hkv, dh), page_map),
+        pl.BlockSpec((1, bs, hkv, dp), page_map),
+        pl.BlockSpec((1, bs, hkv, dp), page_map),
     ]
-    # keep int8 codes int8 on the wire — halving the page DMA bytes is the
-    # point; everything else is normalized to f32 before the call
+    # pages cross HBM in the pool's own dtype (bf16, or int8 codes) and
+    # are upcast a page at a time in VMEM: a cast of the pool in front of
+    # the call would copy every page, live or not, each step
     operands = [
         table.astype(jnp.int32),
         pos.astype(jnp.int32),
         q.astype(jnp.float32),
+        k_pages,
+        v_pages,
     ]
-    with jax.named_scope("engine.pool_cast"):
-        operands += [
-            k_pages if int8 else k_pages.astype(jnp.float32),
-            v_pages if int8 else v_pages.astype(jnp.float32),
-        ]
     if int8:
         in_specs += [
             pl.BlockSpec((1, bs, hkv), scale_map),

@@ -63,8 +63,8 @@ def _kernel(
     tbl_ref,   # (W,) int32 SMEM (scalar prefetch): the request's block table
     q0_ref,    # (1,) int32 SMEM (scalar prefetch): first query position
     q_ref,     # (Hkv, G, tq, Dh) f32 — one tile of head-major queries
-    k_ref,     # (1, bs, Hkv, Dh) f32 (or int8 codes) — page tbl[w]
-    v_ref,     # (1, bs, Hkv, Dh) f32 (or int8 codes)
+    k_ref,     # (1, bs, Hkv, Dp) f32 (or int8 codes) — page tbl[w]
+    v_ref,     # (1, bs, Hkv, Dp) f32 (or int8 codes); Dp ≥ Dh
     *rest,     # int8: ks_ref, vs_ref (1, bs, Hkv) f32, then o/m/l/acc refs
     nw: int,
     bs: int,
@@ -96,8 +96,9 @@ def _kernel(
     @pl.when(w * bs <= q0_ref[0] + jnp.minimum(qi * tq + tq, s) - 1)
     def _block():
         q = q_ref[...].reshape(hkv, r, dh) * jnp.float32(dh**-0.5)
-        k = k_ref[0].astype(jnp.float32)    # (bs, Hkv, Dh)
-        v = v_ref[0].astype(jnp.float32)
+        # the head's lanes of the page (rows may be padded past it)
+        k = k_ref[0, :, :, :dh].astype(jnp.float32)    # (bs, Hkv, Dh)
+        v = v_ref[0, :, :, :dh].astype(jnp.float32)
         sc = jnp.einsum(
             "krd,tkd->krt", q, k, preferred_element_type=jnp.float32
         )                                   # (Hkv, G·tq, bs)
@@ -152,7 +153,7 @@ def _query_tile(s: int) -> int:
 
 def paged_prefill_attention_pallas(
     q: jax.Array,        # (S, H, Dh) f32 — the suffix chunk's queries
-    k_pages: jax.Array,  # (P, bs, Hkv, Dh) f32 (or int8 codes) block pool
+    k_pages: jax.Array,  # (P, bs, Hkv, Dp≥Dh) pool dtype (or int8 codes)
     v_pages: jax.Array,
     table: jax.Array,    # (W,) int32 page ids; <0 treated as page 0
     q0: jax.Array,       # () int32 absolute position of the first query
@@ -169,10 +170,13 @@ def paged_prefill_attention_pallas(
 
     Pass int8 ``k_pages``/``v_pages`` together with ``k_scale``/``v_scale``
     planes to run the fused-dequant path (int8 page DMA, scaling in VMEM).
+    Page rows may be wider than the head (``Dp``, zero-padded to whole
+    lanes as the engine's pools are): a whole row crosses HBM and the
+    kernel computes on the head's ``Dh`` lanes of it.
     """
     s, h, dh = q.shape
-    n_pages, bs, hkv, dh2 = k_pages.shape
-    assert dh == dh2 and h % hkv == 0, (q.shape, k_pages.shape)
+    n_pages, bs, hkv, dp = k_pages.shape
+    assert dh <= dp and h % hkv == 0, (q.shape, k_pages.shape)
     int8 = k_scale is not None
     if int8:
         assert v_scale is not None
@@ -200,8 +204,8 @@ def paged_prefill_attention_pallas(
     scale_map = lambda qi, wi, tbl, p0: (jnp.maximum(tbl[wi], 0), 0, 0)
     in_specs = [
         pl.BlockSpec((hkv, g, tq, dh), q_map),
-        pl.BlockSpec((1, bs, hkv, dh), page_map),
-        pl.BlockSpec((1, bs, hkv, dh), page_map),
+        pl.BlockSpec((1, bs, hkv, dp), page_map),
+        pl.BlockSpec((1, bs, hkv, dp), page_map),
     ]
     # keep int8 codes int8 on the wire — halving the page DMA bytes is the
     # point; everything else is normalized to f32 before the call

@@ -100,7 +100,7 @@ def wta_counts_ref(
 
 def paged_attention_ref(
     q: jax.Array,        # (B, H, Dh)
-    k_pages: jax.Array,  # (P, bs, Hkv, Dh) — cache dtype or int8 codes
+    k_pages: jax.Array,  # (P, bs, Hkv, Dp≥Dh) — cache dtype or int8 codes
     v_pages: jax.Array,
     table: jax.Array,    # (B, W) int32 page ids; <0 treated as page 0
     pos: jax.Array,      # (B,) int32 last valid key position
@@ -127,8 +127,9 @@ def paged_attention_ref(
     _, bs, hkv, _ = k_pages.shape
     g = h // hkv
     pages = jnp.maximum(table, 0)
-    kb = k_pages[pages].reshape(b, -1, hkv, dh)
-    vb = v_pages[pages].reshape(b, -1, hkv, dh)
+    # page rows may be zero-padded past the head (whole-lane pools)
+    kb = k_pages[pages][..., :dh].reshape(b, -1, hkv, dh)
+    vb = v_pages[pages][..., :dh].reshape(b, -1, hkv, dh)
     t = kb.shape[1]
     qg = q.reshape(b, hkv, g, dh).astype(jnp.float32) * jnp.float32(
         dh**-0.5
@@ -160,7 +161,7 @@ def paged_attention_ref(
 
 def prefill_attention_ref(
     q: jax.Array,        # (S, H, Dh) — one request's suffix-chunk queries
-    k_pages: jax.Array,  # (P, bs, Hkv, Dh) — cache dtype or int8 codes
+    k_pages: jax.Array,  # (P, bs, Hkv, Dp≥Dh) — cache dtype or int8 codes
     v_pages: jax.Array,
     table: jax.Array,    # (W,) int32 page ids; <0 treated as page 0
     q0: jax.Array,       # () int32 absolute position of the first query
@@ -187,8 +188,8 @@ def prefill_attention_ref(
     _, bs, hkv, _ = k_pages.shape
     g = h // hkv
     pages = jnp.maximum(table, 0)
-    kb = k_pages[pages].reshape(-1, hkv, dh)
-    vb = v_pages[pages].reshape(-1, hkv, dh)
+    kb = k_pages[pages][..., :dh].reshape(-1, hkv, dh)
+    vb = v_pages[pages][..., :dh].reshape(-1, hkv, dh)
     t = kb.shape[0]
     qg = q.reshape(s, hkv, g, dh).astype(jnp.float32) * jnp.float32(
         dh**-0.5

@@ -391,6 +391,16 @@ def decode_self_attention(
 # ---------------------------------------------------------------------------
 
 
+def _fit_rows(new: jax.Array, pages: jax.Array) -> jax.Array:
+    """``new`` in the pool's dtype, its last axis zero-padded to the
+    pool's (K/V rows hold the head dim padded to whole 128-lane rows,
+    models.transformer.paged_head_dim; scale planes are not padded)."""
+    pad = pages.shape[-1] - new.shape[-1]
+    if pad:
+        new = jnp.pad(new, [(0, 0)] * (new.ndim - 1) + [(0, pad)])
+    return new.astype(pages.dtype)
+
+
 def paged_write(
     pages: jax.Array,   # (P, bs, ...) block pool (K/V or scale planes)
     new: jax.Array,     # (B, 1, ...) this step's K/V rows or scales
@@ -416,7 +426,7 @@ def paged_write(
     blk = jnp.clip(pos // bs, 0, table.shape[1] - 1)
     page_ids = jnp.take_along_axis(table, blk[:, None], axis=1)[:, 0]
     page_ids = jnp.maximum(page_ids, 0)  # unassigned (-1) → trash page 0
-    return pages.at[page_ids, pos % bs].set(new[:, 0].astype(pages.dtype))
+    return pages.at[page_ids, pos % bs].set(_fit_rows(new[:, 0], pages))
 
 
 def paged_gather(pages: jax.Array, table: jax.Array) -> jax.Array:
@@ -454,7 +464,7 @@ def paged_write_chunk(
     compile per (bucket, chunk shape) serves every page set."""
     nbc = new.shape[0]
     ids = jax.lax.dynamic_slice(table_row, (b0,), (nbc,))
-    return pages.at[jnp.maximum(ids, 0)].set(new.astype(pages.dtype))
+    return pages.at[jnp.maximum(ids, 0)].set(_fit_rows(new, pages))
 
 
 def _chunk_to_blocks(x: jax.Array, bs: int) -> jax.Array:
@@ -468,16 +478,18 @@ def _chunk_to_blocks(x: jax.Array, bs: int) -> jax.Array:
 def paged_prefill_self_attention(
     p: dict,
     x: jax.Array,        # (1, c, D) — one request's suffix chunk
-    k_pages: jax.Array,  # (P, bs, Hkv, Dh) — this layer's block pool
+    k_pages: jax.Array,  # (L·P, bs, Hkv, Dp) — every layer's block pool
     v_pages: jax.Array,
     table_row: jax.Array,  # (Wp,) int32 — blocks covering the prompt bucket
     q0: jax.Array,       # () int32 absolute position of the chunk's start
     bucket: int,         # static padded prompt length (table covers it)
     cfg: ModelConfig,
     kind: str = "global",
-    k_scale_pages: Optional[jax.Array] = None,  # (P, bs, Hkv) int8 pools
+    k_scale_pages: Optional[jax.Array] = None,  # (L·P, bs, Hkv) int8 pools
     v_scale_pages: Optional[jax.Array] = None,
     quant_seeds: Optional[jax.Array] = None,    # (nbc,) uint32, int8 pools
+    page_base: jax.Array | int = 0,  # this layer's first page in the pools
+    layer_pages: Optional[int] = None,  # P (default: the whole pool)
 ):
     """Suffix-chunk attention against the paged pool (the chunked-prefill
     building block).  Writes the chunk's K/V into its own pages, then the
@@ -485,6 +497,12 @@ def paged_prefill_self_attention(
     shared prefix pages and the chunk's fresh pages alike — with absolute
     position offsets, so a suffix that starts mid-prompt masks exactly as
     if the full prompt had been prefilled monolithically.
+
+    As in :func:`paged_decode_self_attention`, the pools are the stacked
+    pools of every attention layer, flattened over their leading axes;
+    this layer's pages are ``[page_base, page_base + layer_pages)``.  The
+    chunk's rows land in the stack in place; attention reads a slice of
+    this layer's pages.
 
     On TPU the gather+attend runs as the fused Pallas chunked-prefill
     kernel (kernels/prefill_attention.py).  Off TPU the bf16 path is the
@@ -502,6 +520,11 @@ def paged_prefill_self_attention(
     int8_pool = k_pages.dtype == jnp.int8
     b, c, _ = x.shape
     bs = k_pages.shape[1]
+    rows = jnp.maximum(table_row, 0) + page_base   # pages in the stack
+    layer = functools.partial(
+        jax.lax.dynamic_slice_in_dim, start_index=page_base,
+        slice_size=layer_pages or k_pages.shape[0],
+    )
     positions = jnp.broadcast_to(q0 + jnp.arange(c)[None], (b, c))
     q, k, v = qkv(p, x, cfg, None)
     q = apply_rope(q, positions, cfg.rope_theta)
@@ -523,32 +546,34 @@ def paged_prefill_self_attention(
                 ks.append(ksc)
                 vc.append(v8)
                 vs.append(vsc)
-            k_pages = paged_write_chunk(k_pages, jnp.stack(kc), table_row, b0)
-            v_pages = paged_write_chunk(v_pages, jnp.stack(vc), table_row, b0)
+            k_pages = paged_write_chunk(k_pages, jnp.stack(kc), rows, b0)
+            v_pages = paged_write_chunk(v_pages, jnp.stack(vc), rows, b0)
             k_scale_pages = paged_write_chunk(
-                k_scale_pages, jnp.stack(ks), table_row, b0
+                k_scale_pages, jnp.stack(ks), rows, b0
             )
             v_scale_pages = paged_write_chunk(
-                v_scale_pages, jnp.stack(vs), table_row, b0
+                v_scale_pages, jnp.stack(vs), rows, b0
             )
         else:
-            k_pages = paged_write_chunk(k_pages, kb, table_row, b0)
-            v_pages = paged_write_chunk(v_pages, vb, table_row, b0)
+            k_pages = paged_write_chunk(k_pages, kb, rows, b0)
+            v_pages = paged_write_chunk(v_pages, vb, rows, b0)
     if KOPS.tpu_kernels() or int8_pool:
         out = KOPS.paged_prefill_attention(
-            q[0], k_pages, v_pages, table_row, q0,
+            q[0], layer(k_pages), layer(v_pages), table_row, q0,
             kind=kind,
             local_window=cfg.local_window,
             softcap=cfg.attn_softcap,
-            k_scale=k_scale_pages if int8_pool else None,
-            v_scale=v_scale_pages if int8_pool else None,
+            k_scale=layer(k_scale_pages) if int8_pool else None,
+            v_scale=layer(v_scale_pages) if int8_pool else None,
         )[None].astype(x.dtype)          # (1, c, H, Dh)
     else:
         # bit-parity route with the monolithic prefill: gather the window,
         # slice it to exactly the bucket length (same key chunking as
         # attend_full over the full prompt), same online-softmax helper
-        k_buf = paged_gather(k_pages, table_row[None])[:, :bucket]
-        v_buf = paged_gather(v_pages, table_row[None])[:, :bucket]
+        dh = cfg.head_dim
+        k_buf = paged_gather(layer(k_pages), table_row[None])[:, :bucket]
+        v_buf = paged_gather(layer(v_pages), table_row[None])[:, :bucket]
+        k_buf, v_buf = k_buf[..., :dh], v_buf[..., :dh]
         qpos = q0 + jnp.arange(c)
         kpos = jnp.arange(bucket)
         out = attend_full(q, k_buf, v_buf, qpos, kpos, kind, cfg)
@@ -563,19 +588,26 @@ def paged_prefill_self_attention(
 def paged_decode_self_attention(
     p: dict,
     x: jax.Array,        # (B, 1, D)
-    k_pages: jax.Array,  # (P, bs, Hkv, Dh) — this layer's block pool
+    k_pages: jax.Array,  # (L·P, bs, Hkv, Dp) — every layer's block pool
     v_pages: jax.Array,
     table: jax.Array,    # (B, W) int32 block table (W·bs covers max(pos)+1)
     pos: jax.Array,      # (B,) int32
     cfg: ModelConfig,
     kind: str = "global",
     use_rope: bool = True,
-    k_scale_pages: Optional[jax.Array] = None,  # (P, bs, Hkv) int8 pools
+    k_scale_pages: Optional[jax.Array] = None,  # (L·P, bs, Hkv) int8 pools
     v_scale_pages: Optional[jax.Array] = None,
     quant_seed: Optional[jax.Array] = None,     # uint32 scalar, int8 pools
     write: bool = True,
+    page_base: jax.Array | int = 0,  # this layer's first page in the pools
 ):
     """One-token attention against a paged (block-table) KV cache.
+
+    The pools are the stacked pools of every attention layer, flattened
+    over their leading (unit, sublayer, page) axes; this layer's pages are
+    ``[page_base, page_base + P)``, so table entry ``t`` names page
+    ``max(t, 0) + page_base``.  Reads and writes address that stack in
+    place: no layer's pool is ever sliced out, cast, or stacked back.
 
     Writes this step's K/V into each slot's current block, then attends over
     the W gathered blocks only — O(W·bs) work per token instead of
@@ -597,6 +629,7 @@ def paged_decode_self_attention(
     for int8 pools.
     """
     int8_pool = k_pages.dtype == jnp.int8
+    table = jnp.maximum(table, 0) + page_base
     q, k, v = qkv(p, x, cfg, None)
     if use_rope:
         q = apply_rope(q, pos[:, None], cfg.rope_theta)
@@ -628,8 +661,9 @@ def paged_decode_self_attention(
             v_scale=v_scale_pages if int8_pool else None,
         )[:, None].reshape(x.shape[0], 1, -1)
     else:
-        k_buf = paged_gather(k_pages, table)
-        v_buf = paged_gather(v_pages, table)
+        dh = cfg.head_dim
+        k_buf = paged_gather(k_pages, table)[..., :dh]
+        v_buf = paged_gather(v_pages, table)[..., :dh]
         out = attend_one_token(
             q, k_buf, v_buf, pos, cfg, kind,
             k_scale=paged_gather(k_scale_pages, table)

@@ -311,12 +311,18 @@ def init_decode_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     return cache
 
 
+def paged_head_dim(head_dim: int) -> int:
+    """The last axis of a K/V page pool: ``head_dim`` rounded up to whole
+    128-lane rows (see :func:`init_paged_decode_cache`)."""
+    return -(-head_dim // 128) * 128
+
+
 def init_paged_decode_cache(
     cfg: ModelConfig, batch: int, n_pages: int, block_size: int
 ) -> dict:
     """Paged-layout cache: a shared pool of fixed-size KV blocks.
 
-    Attention K/V live in (nu, n_attn, n_pages, block_size, Hkv, Dh) pools
+    Attention K/V live in (nu, n_attn, n_pages, block_size, Hkv, Dp) pools
     shared by ALL slots; which pages a slot owns is the engine's block
     table (host state, passed to the decode step each tick).  Capacity is
     pooled: n_pages · block_size tokens total, instead of the dense
@@ -334,6 +340,16 @@ def init_paged_decode_cache(
     scale planes; writes quantize with unbiased stochastic rounding and
     reads fold the scales into the attention math (see
     attention.paged_decode_self_attention).
+
+    A K/V row holds the head dim padded with zeros to whole 128-lane
+    vregs (:func:`paged_head_dim`); readers take the first ``head_dim``
+    lanes.  The TPU lays a row-padded pool out row-major, page by page,
+    which is what lets the paged kernels DMA a page straight out of the
+    stacked pool.  For a head dim that is not a multiple of 128 (80 in
+    stablelm-3b) its default layout would put the page axis minor
+    instead, the layout that pads least, and every program would convert
+    the whole pool on entry and back on exit.  The padding costs no HBM
+    over a row-major pool, which pads to whole vregs anyway.
     """
     dt = dtype_of(cfg)
     nu = cfg.n_units
@@ -342,7 +358,8 @@ def init_paged_decode_cache(
     n_attn = sum(1 for k in pat if k in ("global", "local"))
     if n_attn:
         shape = (
-            nu, n_attn, n_pages, block_size, cfg.n_kv_heads, cfg.head_dim
+            nu, n_attn, n_pages, block_size, cfg.n_kv_heads,
+            paged_head_dim(cfg.head_dim),
         )
         if cfg.kv_cache_dtype == "int8":
             cache["k_pages"] = jnp.zeros(shape, jnp.int8)
@@ -373,26 +390,27 @@ PAGE_POOL_LEAVES = (
 def _unit_decode(
     x: jax.Array,         # (B,1,D)
     up: dict,
-    ucache: dict,
+    ucache: dict,         # this unit's dense per-slot leaves
+    pool: dict,           # flattened stacked page pools (paged cache)
     pos: jax.Array,       # (B,)
     cfg: ModelConfig,
     table: Optional[jax.Array] = None,  # (B, W) block table (paged cache)
     uidx: jax.Array | int = 0,          # unit index (seeds int8 rounding)
     quant_base: Optional[jax.Array] = None,  # engine-wide decode counter
     kv_write: bool = True,
-) -> tuple[jax.Array, dict]:
-    # kv_write=False is the speculative-verify cell: identical math, but
-    # the K/V pool is read-only (the draft steps already wrote these
-    # positions) and the pool leaves are dropped from the returned cache
-    # so a scan over verify cells never carries or re-stacks the pool.
-    paged = "k_pages" in ucache
-    int8_pool = "k_scale_pages" in ucache
-    if kv_write:
-        new_cache = dict(ucache)
-    else:
-        new_cache = {
-            k: v for k, v in ucache.items() if k not in PAGE_POOL_LEAVES
-        }
+) -> tuple[jax.Array, dict, dict]:
+    # The paged pools arrive whole, as (nu·n_attn·P, bs, ...) views of
+    # the (nu, n_attn, P, bs, ...) stack: each sublayer reads and writes
+    # its own pages at offset (uidx·n_attn + i_attn)·P, so no layer's
+    # pool is ever sliced out or stacked back.  kv_write=False is the
+    # speculative-verify cell: identical math, but the pool is read-only
+    # (the draft steps already wrote these positions).
+    paged = "k_pages" in pool
+    int8_pool = "k_scale_pages" in pool
+    if paged:
+        n_attn = sum(1 for k in cfg.layer_pattern if k in ("global", "local"))
+        n_pages = pool["k_pages"].shape[0] // (cfg.n_units * n_attn)
+    new_cache = dict(ucache)
     i_attn = i_rec = i_ssm = 0
     for i, kind in enumerate(cfg.layer_pattern):
         sub = up[f"l{i}"]
@@ -401,57 +419,41 @@ def _unit_decode(
             # the norm/FFN tail below is shared so the layouts cannot drift
             if paged:
                 scale_kw = {}
-                if not kv_write and int8_pool:
+                if int8_pool:
                     scale_kw = dict(
-                        k_scale_pages=ucache["k_scale_pages"][i_attn],
-                        v_scale_pages=ucache["v_scale_pages"][i_attn],
+                        k_scale_pages=pool["k_scale_pages"],
+                        v_scale_pages=pool["v_scale_pages"],
                     )
-                elif int8_pool:
+                if int8_pool and kv_write:
                     # per-(decode step, unit, sublayer) counter-PRNG seed:
                     # quant_base ticks monotonically per lm_decode_step, so
                     # every cache write draws fresh unbiased rounding noise
                     # over the engine's lifetime; the per-element counter
                     # inside stoch_round decorrelates slots/heads within
                     # one write
-                    seed = (
+                    scale_kw["quant_seed"] = (
                         quant_base.astype(jnp.uint32)
                         * jnp.uint32(2654435761)
                         + jnp.asarray(uidx).astype(jnp.uint32)
                         * jnp.uint32(40503)
                         + jnp.uint32(i * 1299721)
                     )
-                    scale_kw = dict(
-                        k_scale_pages=ucache["k_scale_pages"][i_attn],
-                        v_scale_pages=ucache["v_scale_pages"][i_attn],
-                        quant_seed=seed,
-                    )
                 res = ATT.paged_decode_self_attention(
                     sub["attn"],
                     rmsnorm(sub["ln1"], x, cfg.norm_eps),
-                    ucache["k_pages"][i_attn],
-                    ucache["v_pages"][i_attn],
+                    pool["k_pages"],
+                    pool["v_pages"],
                     table,
                     pos,
                     cfg,
                     kind=kind,
                     write=kv_write,
+                    page_base=(uidx * n_attn + i_attn) * n_pages,
                     **scale_kw,
                 )
-                a, kp, vp = res[:3]
+                a = res[0]
                 if kv_write:
-                    new_cache["k_pages"] = (
-                        new_cache["k_pages"].at[i_attn].set(kp)
-                    )
-                    new_cache["v_pages"] = (
-                        new_cache["v_pages"].at[i_attn].set(vp)
-                    )
-                    if int8_pool:
-                        new_cache["k_scale_pages"] = (
-                            new_cache["k_scale_pages"].at[i_attn].set(res[3])
-                        )
-                        new_cache["v_scale_pages"] = (
-                            new_cache["v_scale_pages"].at[i_attn].set(res[4])
-                        )
+                    pool = dict(zip(PAGE_POOL_LEAVES, res[1:]))
             else:
                 int8 = cfg.kv_cache_dtype == "int8"
                 res = ATT.decode_self_attention(
@@ -514,7 +516,7 @@ def _unit_decode(
             new_cache["ssm_state"] = new_cache["ssm_state"].at[i_ssm].set(st)
             i_ssm += 1
             x = x + o
-    return x, new_cache
+    return x, new_cache, pool
 
 
 def lm_decode_step(
@@ -540,19 +542,28 @@ def lm_decode_step(
     x = embed(params["embed"], token[:, None], cfg)
 
     def body(carry, xs):
-        h = carry
+        h, pl = carry
         up, uc, uidx = xs
-        h, uc_new = _unit_decode(
-            h, up, uc, pos, cfg, table, uidx, qstep, kv_write
+        h, uc_new, pl = _unit_decode(
+            h, up, uc, pl, pos, cfg, table, uidx, qstep, kv_write
         )
-        return h, uc_new
+        return (h, pl), uc_new
 
+    # The page pools ride the carry whole, viewed as (nu·n_attn·P, bs, ...)
+    # (a merge of leading axes, a bitcast of a row-major pool): the scan
+    # slices and restacks only the dense per-slot leaves.
+    pool = {
+        k: cache[k].reshape((-1,) + cache[k].shape[3:])
+        for k in PAGE_POOL_LEAVES if k in cache
+    }
     layer_cache = {
-        k: v for k, v in cache.items() if k not in ("pos", "quant_step")
+        k: v for k, v in cache.items()
+        if k not in pool and k not in ("pos", "quant_step")
     }
     if cfg.scan_layers:
-        x, new_layer_cache = jax.lax.scan(
-            body, x, (params["units"], layer_cache, jnp.arange(cfg.n_units)),
+        (x, pool), new_layer_cache = jax.lax.scan(
+            body, (x, pool),
+            (params["units"], layer_cache, jnp.arange(cfg.n_units)),
             unroll=True if cfg.cost_exact else 1,
         )
     else:
@@ -560,15 +571,17 @@ def lm_decode_step(
         for u in range(cfg.n_units):
             up = jax.tree.map(lambda a: a[u], params["units"])
             uc = jax.tree.map(lambda a: a[u], layer_cache)
-            x, uc_new = body(x, (up, uc, u))
+            (x, pool), uc_new = body((x, pool), (up, uc, u))
             ys.append(uc_new)
         new_layer_cache = jax.tree.map(lambda *a: jnp.stack(a), *ys)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = logits_out(params["embed"], params.get("head"), x, cfg)
     new_cache = dict(new_layer_cache)
     new_cache["pos"] = pos + 1
-    if qstep is not None and kv_write:
-        new_cache["quant_step"] = qstep + 1
+    if kv_write:
+        new_cache.update({k: pool[k].reshape(cache[k].shape) for k in pool})
+        if qstep is not None:
+            new_cache["quant_step"] = qstep + 1
     return new_cache, logits[:, 0, :]
 
 
@@ -621,11 +634,12 @@ def lm_prefill_chunk(
     positions = jnp.broadcast_to(q0 + jnp.arange(c)[None], (b, c))
     int8_pool = "k_scale_pages" in pool
     layer_state = {k: v for k, v in state.items() if k != "pos"}
+    n_attn = sum(1 for k in cfg.layer_pattern if k in ("global", "local"))
+    n_pages = pool["k_pages"].shape[2] if "k_pages" in pool else 0
 
     def body(carry, xs):
-        h = carry
-        up, uc, us, uidx = xs
-        new_uc = dict(uc)
+        h, pl = carry
+        up, us, uidx = xs
         new_us = dict(us)
         ia = ir = ism = 0
         for i, kind in enumerate(cfg.layer_pattern):
@@ -639,8 +653,8 @@ def lm_prefill_chunk(
                     # function of (block content, block position, layer) —
                     # the property that keeps int8 blocks shareable
                     kw = dict(
-                        k_scale_pages=uc["k_scale_pages"][ia],
-                        v_scale_pages=uc["v_scale_pages"][ia],
+                        k_scale_pages=pl["k_scale_pages"],
+                        v_scale_pages=pl["v_scale_pages"],
                         quant_seeds=(
                             quant_seeds
                             + jnp.asarray(uidx).astype(jnp.uint32)
@@ -649,20 +663,13 @@ def lm_prefill_chunk(
                         ),
                     )
                 res = ATT.paged_prefill_self_attention(
-                    sub["attn"], hin,
-                    uc["k_pages"][ia], uc["v_pages"][ia],
-                    table_row, q0, bucket, cfg, kind=kind, **kw,
+                    sub["attn"], hin, pl["k_pages"], pl["v_pages"],
+                    table_row, q0, bucket, cfg, kind=kind,
+                    page_base=(uidx * n_attn + ia) * n_pages,
+                    layer_pages=n_pages, **kw,
                 )
-                o, kp, vp = res[:3]
-                new_uc["k_pages"] = new_uc["k_pages"].at[ia].set(kp)
-                new_uc["v_pages"] = new_uc["v_pages"].at[ia].set(vp)
-                if int8_pool:
-                    new_uc["k_scale_pages"] = (
-                        new_uc["k_scale_pages"].at[ia].set(res[3])
-                    )
-                    new_uc["v_scale_pages"] = (
-                        new_uc["v_scale_pages"].at[ia].set(res[4])
-                    )
+                o = res[0]
+                pl = dict(zip(PAGE_POOL_LEAVES, res[1:]))
                 if cfg.post_norms:
                     o = rmsnorm(sub["post_ln1"], o, cfg.norm_eps)
                 h = h + o
@@ -699,16 +706,20 @@ def lm_prefill_chunk(
                 new_us["ssm_state"] = new_us["ssm_state"].at[ism].set(st)
                 h = h + o
                 ism += 1
-        return h, (new_uc, new_us)
+        return (h, pl), new_us
 
     # always scan over units — :func:`lm_prefill` scans unconditionally
     # (unlike the decode step, which branches on ``scan_layers``), and the
-    # bit-identity anchor requires the exact same HLO structure
-    x, (new_pool, new_layer_state) = jax.lax.scan(
-        body, x,
-        (params["units"], pool, layer_state, jnp.arange(cfg.n_units)),
+    # bit-identity anchor requires the exact same HLO structure.  The
+    # pools ride the carry whole, as in :func:`lm_decode_step`: the chunk
+    # writes its rows into the stack in place, and nothing re-stacks it.
+    flat = {k: v.reshape((-1,) + v.shape[3:]) for k, v in pool.items()}
+    (x, flat), new_layer_state = jax.lax.scan(
+        body, (x, flat),
+        (params["units"], layer_state, jnp.arange(cfg.n_units)),
         unroll=True if cfg.cost_exact else 1,
     )
+    new_pool = {k: flat[k].reshape(pool[k].shape) for k in pool}
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     rows = x if all_logits else x[:, -1:, :]
     logits = logits_out(params["embed"], params.get("head"), rows, cfg)

@@ -262,6 +262,49 @@ def test_paged_attention_ignores_blocks_beyond_pos():
     )
 
 
+@pytest.mark.parametrize("int8", [False, True])
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_paged_kernels_read_lane_padded_pages(kernel, int8):
+    """The engine's pools hold each head of 16 zero-padded to a 128-lane
+    row: the interpret-mode kernels read such pages and agree with the
+    oracle on the unpadded pages (the softmax scale stays the head's,
+    16**-0.5), and the oracle reads padded pages bit for bit as it reads
+    unpadded ones."""
+    from repro.kernels.paged_attention import paged_attention_pallas
+    from repro.kernels.prefill_attention import paged_prefill_attention_pallas
+
+    bs, w = 8, 3
+    if kernel == "decode":
+        q, kp, vp, table = _paged_case(4, 2, 4, 2, 16, 12, bs, w)
+        idx = jnp.asarray([20, 9], jnp.int32)
+        run = paged_attention_pallas
+    else:
+        q, kp, vp, table = _suffix_case(4, 6, 4, 2, 16, 12, bs, w)
+        idx = jnp.asarray(8, jnp.int32)
+        run = paged_prefill_attention_pallas
+    ref_fn = (ops.ref.paged_attention_ref if kernel == "decode"
+              else ops.ref.prefill_attention_ref)
+    sc = {}
+    if int8:
+        kp = (kp * 40).astype(jnp.int8)
+        vp = (vp * 40).astype(jnp.int8)
+        sc = dict(
+            k_scale=jnp.abs(kp[..., 0].astype(jnp.float32)) / 40 + 0.1,
+            v_scale=jnp.abs(vp[..., 0].astype(jnp.float32)) / 40 + 0.1,
+        )
+    pad = ((0, 0),) * 3 + ((0, 128 - 16),)
+    kpp, vpp = jnp.pad(kp, pad), jnp.pad(vp, pad)
+    want = ref_fn(q, kp, vp, table, idx, **sc)
+    got = run(q, kpp, vpp, table, idx, interpret=True, **sc)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=2e-5, rtol=1e-5
+    )
+    np.testing.assert_array_equal(
+        np.asarray(ref_fn(q, kpp, vpp, table, idx, **sc)), np.asarray(want)
+    )
+
+
 def test_paged_attention_op_dispatches_off_tpu():
     """ops.paged_attention falls back to the oracle off-TPU (the serving
     hot loop must not run interpret-mode emulation)."""

@@ -121,12 +121,18 @@ P, BS = 9, 8  # pool pages (page 0 = trash), tokens per block
 
 
 def test_paged_cache_layout():
-    """Attention K/V become shared (pool, block) leaves; pos and the
-    recurrent states keep the dense slot layout."""
+    """Attention K/V become shared (pool, block) leaves, their rows the
+    head dim zero-padded to whole 128-lane rows (16 → 128 here); pos and
+    the recurrent states keep the dense slot layout."""
+    from repro.models import transformer as TF
+
     cfg = get_smoke_config("stablelm-3b")
     specs = SP.paged_decode_cache_specs(cfg, B, P, BS)
     n_attn = sum(1 for k in cfg.layer_pattern if k in ("global", "local"))
-    want = (cfg.n_units, n_attn, P, BS, cfg.n_kv_heads, cfg.head_dim)
+    assert [TF.paged_head_dim(d) for d in (16, 80, 128, 256)] == [
+        128, 128, 128, 256
+    ]
+    want = (cfg.n_units, n_attn, P, BS, cfg.n_kv_heads, 128)
     assert specs["k_pages"].shape == want
     assert specs["v_pages"].shape == want
     assert specs["pos"].shape == (B,)
@@ -209,12 +215,17 @@ def test_suffix_prefill_matches_monolithic_prefill():
             jnp.asarray(0, jnp.int32), bucket=bucket,
         )
         ref_cache, ref_logits = TF.lm_prefill(params, toks, cfg, bucket)
-        kb = np.asarray(out["k_pages"])[:, :, [4, 2]]  # (nu,na,2,BS,H,D)
-        ref_k = np.asarray(ref_cache["k"])[:, :, 0].reshape(kb.shape)
-        np.testing.assert_array_equal(kb, ref_k)
+        dh = cfg.head_dim
+        kb = np.asarray(out["k_pages"])[:, :, [4, 2]]  # (nu,na,2,BS,H,Dp)
+        ref_k = np.asarray(ref_cache["k"])[:, :, 0].reshape(
+            kb.shape[:-1] + (dh,)
+        )
+        np.testing.assert_array_equal(kb[..., :dh], ref_k)
+        np.testing.assert_array_equal(kb[..., dh:], 0)  # zero lane padding
         vb = np.asarray(out["v_pages"])[:, :, [4, 2]]
-        ref_v = np.asarray(ref_cache["v"])[:, :, 0].reshape(vb.shape)
-        np.testing.assert_array_equal(vb, ref_v)
+        ref_v = np.asarray(ref_cache["v"])[:, :, 0].reshape(ref_k.shape)
+        np.testing.assert_array_equal(vb[..., :dh], ref_v)
+        np.testing.assert_array_equal(vb[..., dh:], 0)
         np.testing.assert_array_equal(
             np.asarray(logits), np.asarray(ref_logits)
         )
@@ -310,7 +321,7 @@ def test_paged_cache_int8_layout():
     )
     specs = SP.paged_decode_cache_specs(cfg, B, P, BS)
     n_attn = sum(1 for k in cfg.layer_pattern if k in ("global", "local"))
-    want = (cfg.n_units, n_attn, P, BS, cfg.n_kv_heads, cfg.head_dim)
+    want = (cfg.n_units, n_attn, P, BS, cfg.n_kv_heads, 128)
     assert specs["k_pages"].shape == want
     assert specs["k_pages"].dtype == jnp.int8
     assert specs["v_pages"].dtype == jnp.int8
@@ -416,6 +427,8 @@ def test_int8_suffix_prefill_quantizes_into_covered_pages():
         jnp.asarray(0, jnp.int32), seeds, bucket=bucket,
     )
     kp = np.asarray(out["k_pages"], np.float32)
+    np.testing.assert_array_equal(kp[..., cfg.head_dim:], 0)  # lane padding
+    kp = kp[..., : cfg.head_dim]
     ks = np.asarray(out["k_scale_pages"], np.float32)
     untouched = [p for p in range(P) if p not in (3, 5)]
     np.testing.assert_array_equal(kp[:, :, untouched], 0)
@@ -702,6 +715,142 @@ def test_decode_step_kv_write_false_is_read_only():
     np.testing.assert_array_equal(
         np.asarray(c_ro["pos"]), np.asarray(c_wr["pos"])
     )
+
+
+def _per_layer_decode(params, cache, token, cfg, table):
+    """A decode step that hands each unit its own slice of the pool
+    (the scan's ``xs``), writes and attends there, and stacks the slices
+    back (``ys``): the per-layer dataflow the in-place step replaces
+    (attention-only patterns)."""
+    from repro.models import attention as ATT
+    from repro.models import layers as L
+
+    pos, qstep = cache["pos"], cache.get("quant_step")
+    names = [n for n in SP.PAGE_POOL_LEAVES if n in cache]
+
+    def unit(x, xs):
+        up, pool, u = xs
+        pool = dict(pool)
+        for i, kind in enumerate(cfg.layer_pattern):
+            sub = up[f"l{i}"]
+            kw = {}
+            if "k_scale_pages" in pool:
+                kw = dict(
+                    k_scale_pages=pool["k_scale_pages"][i],
+                    v_scale_pages=pool["v_scale_pages"][i],
+                    quant_seed=qstep.astype(jnp.uint32)
+                    * jnp.uint32(2654435761)
+                    + jnp.asarray(u).astype(jnp.uint32) * jnp.uint32(40503)
+                    + jnp.uint32(i * 1299721),
+                )
+            res = ATT.paged_decode_self_attention(
+                sub["attn"], L.rmsnorm(sub["ln1"], x, cfg.norm_eps),
+                pool["k_pages"][i], pool["v_pages"][i], table, pos,
+                cfg, kind=kind, **kw,
+            )
+            for n, layer in zip(names, res[1:]):
+                pool[n] = pool[n].at[i].set(layer)
+            a = res[0]
+            if cfg.post_norms:
+                a = L.rmsnorm(sub["post_ln1"], a, cfg.norm_eps)
+            x = x + a
+            f = L.mlp_apply(
+                sub["ffn"], L.rmsnorm(sub["ln2"], x, cfg.norm_eps), cfg, None
+            )
+            if cfg.post_norms:
+                f = L.rmsnorm(sub["post_ln2"], f, cfg.norm_eps)
+            x = x + f
+        return x, pool
+
+    x = L.embed(params["embed"], token[:, None], cfg)
+    pool = {n: cache[n] for n in names}
+    if cfg.scan_layers:
+        x, pool = jax.lax.scan(
+            unit, x, (params["units"], pool, jnp.arange(cfg.n_units))
+        )
+    else:
+        ys = []
+        for u in range(cfg.n_units):
+            at = lambda a: a[u]
+            x, y = unit(
+                x, (jax.tree.map(at, params["units"]),
+                    jax.tree.map(at, pool), u)
+            )
+            ys.append(y)
+        pool = jax.tree.map(lambda *a: jnp.stack(a), *ys)
+    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    logits = L.logits_out(params["embed"], params.get("head"), x, cfg)
+    return pool, logits[:, 0, :]
+
+
+@pytest.mark.parametrize("scan", [True, False])
+@pytest.mark.parametrize("kv", ["same", "int8"])
+@pytest.mark.parametrize("arch", ["stablelm-3b", "gemma2-2b"])
+def test_paged_decode_step_writes_the_stacked_pool_in_place(arch, kv, scan):
+    """The paged decode step over 3 units (gemma2: two attention
+    sublayers a unit, local and global) writes each token's K/V rows (and
+    int8 scales) straight into the stacked pool: every pool entry no slot
+    wrote is bit-identical after the step, and the written rows and the
+    logits equal the per-layer slice / write / re-stack replay bit for
+    bit (same rows, same rounding seeds, same places)."""
+    from repro.models import get_model_fns
+    from repro.models import transformer as TF
+
+    base = get_smoke_config(arch)
+    cfg = dataclasses.replace(
+        base, n_layers=3 * len(base.layer_pattern), kv_cache_dtype=kv,
+        scan_layers=scan,
+    )
+    assert cfg.n_units == 3
+    assert set(cfg.layer_pattern) <= {"global", "local"}
+    params = get_model_fns(cfg).init(jax.random.PRNGKey(0), cfg)
+    n_pages, bs, nb = 10, 4, 4
+    cache = SP.init_paged_decode_cache(cfg, nb, n_pages, bs)
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 8))
+    for n in SP.PAGE_POOL_LEAVES:
+        if n in cache:
+            leaf = cache[n]
+            if leaf.dtype == jnp.int8:
+                val = jax.random.randint(next(keys), leaf.shape, -127, 128)
+            else:
+                val = jax.random.uniform(next(keys), leaf.shape, minval=0.5)
+            cache[n] = val.astype(leaf.dtype)
+    cache["pos"] = jnp.asarray([5, 9, 2, 7], jnp.int32)
+    if "quant_step" in cache:
+        cache["quant_step"] = jnp.int32(11)
+    # slots 0-2 own distinct pages; slot 3 is parked on the trash page
+    table = jnp.asarray(
+        [[3, 1, 0], [2, 5, 9], [7, 0, 0], [-1, -1, -1]], jnp.int32
+    )
+    token = jnp.asarray([4, 17, 9, 0], jnp.int32)
+
+    new, logits = jax.jit(
+        lambda p, c, t: TF.lm_decode_step(p, c, t, cfg, table)
+    )(params, dict(cache), token)
+    want_pool, want_logits = jax.jit(
+        lambda p, c, t: _per_layer_decode(p, c, t, cfg, table)
+    )(params, dict(cache), token)
+
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    pos, tbl = np.asarray(cache["pos"]), np.maximum(np.asarray(table), 0)
+    pages = tbl[np.arange(nb), pos // bs]
+    written = np.zeros((n_pages, bs), bool)
+    written[pages, pos % bs] = True
+    assert written.sum() == nb
+    for n in SP.PAGE_POOL_LEAVES:
+        if n not in cache:
+            continue
+        before = np.asarray(cache[n])
+        after = np.asarray(new[n])
+        assert after.shape == before.shape and after.dtype == before.dtype
+        np.testing.assert_array_equal(
+            after[:, :, ~written], before[:, :, ~written], err_msg=n
+        )
+        np.testing.assert_array_equal(
+            after[:, :, written], np.asarray(want_pool[n])[:, :, written],
+            err_msg=n,
+        )
+        assert (after[:, :, written] != before[:, :, written]).any(), n
 
 
 def test_spec_factories_reject_bad_args():
