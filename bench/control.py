@@ -6,10 +6,11 @@ were set.
 
 Each seed is a whole run of the cell as ``bench/run.py`` makes it (its
 own weights, traffic and window), and on the same sampled requests the
-control (the reference in the next lower precision, see
-``bench/reference.py``) picks its own token at each position.  One JSON
-line per seed: the program's gaps, the control's gaps, and the limits
-they are held to.  The benchmark's own runs never run the control.
+control (the reference of the cell's architecture in the next lower
+precision: ``logits_rows`` with the file's ``control`` as its variant,
+``bench/reference.py`` for dense) picks its own token at each position.
+One JSON line per seed: the program's gaps, the control's gaps, and the
+limits they are held to.  The benchmark's own runs never run the control.
 """
 
 from __future__ import annotations

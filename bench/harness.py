@@ -2,11 +2,13 @@
 
 The system under test is the serving engine as ``launch/serve.py``
 drives it: ``ServingEngine.submit`` and ``tick`` on the paged pool.  The
-harness owns everything else: the weights (``bench/weights.py``), the
-traffic (``bench/traffic.py``), the clock, the spans it traces around
-its calls into the engine (``bench.tick``, ``bench.submit``,
-``bench.wait``, ``bench.window``), the reduction of the trace, the
-metrics, and the comparison with the reference that decides ``correct``.
+harness owns everything else: the weights (``bench/weights.py``, in the
+layout of the cell's architecture), the traffic (``bench/traffic.py``),
+the clock, the spans it traces around its calls into the engine
+(``bench.tick``, ``bench.submit``, ``bench.wait``, ``bench.window``), the
+reductions of the trace (``bench/trace_reduce.py``, and the engine's own
+spans and scopes by ``bench/spans.py``), the metrics, and the comparison
+with the architecture's reference that decides ``correct``.
 """
 
 from __future__ import annotations
@@ -24,11 +26,12 @@ import numpy as np
 
 from bench import reference as REF
 from bench import roofline as RL
+from bench import spans as SPANS
 from bench import spec as SPEC
 from bench import stats as ST
 from bench import trace_reduce as TR
 from bench import traffic as TRAFFIC
-from bench.weights import dims, make_weights
+from bench import weights as WEIGHTS
 
 CACHE_DIR = ".jax_cache"         # under the checkout, listed in .gitignore
 TRACE_DIR = ".bench_trace"       # written and removed by a traced run
@@ -93,23 +96,19 @@ def _program():
     return get_config, params_specs, RequestState, ServeConfig, ServingEngine
 
 
-def model_config(conf: dict, get_config):
+def model_config(conf: dict, get_config, arch):
     """The program's ModelConfig for a configuration file, checked
-    against the sizes the file states."""
+    against the fields that its architecture ``arch`` reads from it (a
+    JSON list among the overrides is a tuple, as ``layer_pattern`` is)."""
     mc = get_config(conf["registry"])
+    overrides = {k: tuple(v) if isinstance(v, list) else v
+                 for k, v in conf.get("overrides", {}).items()}
     mc = dataclasses.replace(
-        mc, **conf.get("overrides", {}),
+        mc, **overrides,
         kv_cache_dtype=conf["kv_cache_dtype"],
         wta_head=conf["sampler"] == "wta",
     )
-    m, arch = dims(conf["model"]), conf["as_run"]
-    want = {
-        "n_layers": m["L"], "d_model": m["D"], "n_heads": m["H"],
-        "n_kv_heads": m["Hkv"], "head_dim": m["Dh"], "d_ff": m["F"],
-        "vocab": m["V"], "rope_theta": float(arch["rope_theta"]),
-        "norm_eps": float(arch["norm_eps"]), "mlp": "swiglu",
-        "layer_pattern": ("global",), "tie_embeddings": False,
-    }
+    want = arch.program_fields(conf["model"], conf["as_run"])
     have = {k: getattr(mc, k) for k in want}
     if have != want:
         bad = {k: (have[k], want[k]) for k in want if have[k] != want[k]}
@@ -412,12 +411,12 @@ def gap_stats(g: np.ndarray) -> dict:
             "p99": float(np.percentile(g, 99))}
 
 
-def check(conf: dict, weights, prompts: dict, outs: dict, sample, buckets,
-          serve_seed: int, control: bool) -> dict:
-    """Gaps of the sampled requests' served tokens under the reference
-    (and, with ``control``, of the control's own choices at the same
-    positions)."""
-    model, arch = conf["model"], conf["as_run"]
+def check(conf: dict, arch, weights, prompts: dict, outs: dict, sample,
+          buckets, serve_seed: int, control: bool) -> dict:
+    """Gaps of the sampled requests' served tokens under the reference of
+    architecture ``arch`` (and, with ``control``, of the control's own
+    choices at the same positions)."""
+    model, as_run = conf["model"], conf["as_run"]
     wta = conf.get("wta") if conf["sampler"] == "wta" else None
     pad = int(conf["pad_token"])
     max_len = int(conf["serve"]["max_len"])
@@ -428,12 +427,12 @@ def check(conf: dict, weights, prompts: dict, outs: dict, sample, buckets,
         seq = padded(prompts[rid], b, pad) + out[:-1]
         seq += [pad] * (max_len - len(seq))
         rows = np.arange(b - 1, b - 1 + len(out))
-        z = REF.logits_rows(weights, model, arch, seq, rows)
+        z = arch.logits_rows(weights, model, as_run, seq, rows)
         keys = token_keys(serve_seed, rid, len(out)) if wta else None
         g.append(REF.gaps(z, keys, out, wta))
         if control:
-            zc = REF.logits_rows(weights, model, arch, seq, rows,
-                                 variant=conf["control"])
+            zc = arch.logits_rows(weights, model, as_run, seq, rows,
+                                  variant=conf["control"])
             gctl.append(REF.gaps(z, keys, REF.control_choice(zc, keys, wta),
                                 wta))
             del zc
@@ -488,12 +487,12 @@ def _run_cell(cell, seed, seconds, trace, require_tpu, control, cache,
     get_config, params_specs, RequestState, ServeConfig, ServingEngine = (
         _program()
     )
-    conf, mix = cell.config, cell.traffic
-    mc = model_config(conf, get_config)
+    conf, mix, arch = cell.config, cell.traffic, cell.arch
+    mc = model_config(conf, get_config, arch)
     model = conf["model"]
-    vocab = dims(model)["V"]
+    vocab = arch.dims(model)["V"]
     serve_seed = seed % (2 ** 31)
-    weights = jax.block_until_ready(make_weights(model, seed))
+    weights = jax.block_until_ready(WEIGHTS.make(arch.layout(model), seed))
     _check_layout(weights, mc, params_specs)
     scfg = ServeConfig(seed=serve_seed, **conf["serve"])
     eng = ServingEngine(weights, mc, scfg)
@@ -522,9 +521,10 @@ def _run_cell(cell, seed, seconds, trace, require_tpu, control, cache,
         f"cache_loads_in_window={in_window_loads} "
         f"generator_late_ms_max={clock.late * 1e3:.3f}")
     res = _end_to_end(run, t_open, t_close, due, warm_rids, setup_s)
-    trace_red = None
+    trace_red = spans_red = None
     if trace:
         trace_red = TR.reduce_dir(run.trace_dir)
+        spans_red = SPANS.reduce_dir(run.trace_dir)
         shutil.rmtree(run.trace_dir, ignore_errors=True)
     # the check: the served requests, then the engine's state is freed
     finished = [
@@ -544,8 +544,9 @@ def _run_cell(cell, seed, seconds, trace, require_tpu, control, cache,
     gc.collect()
     counters = _counters(m_close, run.at_open["metrics"])
     ctx = {
-        "model": model, "kv_dtype": conf["kv_cache_dtype"], "peak": peak,
-        "trace": trace_red, "work": run.work, "counters": counters,
+        "model": model, "arch": arch, "kv_dtype": conf["kv_cache_dtype"],
+        "peak": peak, "trace": trace_red, "spans": spans_red,
+        "work": run.work, "counters": counters,
         "admitted_in_window": res["admitted_in_window"],
     }
     got = {"gap": {}, "tokens_compared": 0, "requests_compared": 0}
@@ -554,7 +555,7 @@ def _run_cell(cell, seed, seconds, trace, require_tpu, control, cache,
         sample = pick_sample(finished, outs, int(conf["check"]["requests"]),
                              check_rng)
         t_chk = time.perf_counter()
-        got = check(conf, weights, prompts, outs, sample, buckets,
+        got = check(conf, arch, weights, prompts, outs, sample, buckets,
                     serve_seed, control)
         got["check_s"] = time.perf_counter() - t_chk
     compared = {f"{k}_gap": {"value": got["gap"][k], "limit": float(v)}

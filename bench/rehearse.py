@@ -45,7 +45,7 @@ def main(argv=None) -> int:
     from repro.launch import specs as SP
 
     ops.tpu_kernels = lambda: True
-    mc = harness.model_config(conf, get_config)
+    mc = harness.model_config(conf, get_config, spec.arch_of(conf))
     scfg = ServeConfig(**conf["serve"])
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")

@@ -314,6 +314,14 @@ def reduce_dir(trace_dir: str) -> dict:
                   op_scopes(xspace))
 
 
+def scope_time(r: dict, program: str, scope: str) -> float:
+    """Device seconds under ``scope`` in the programs whose name matches
+    the pattern ``program``, in a reduction."""
+    rx = re.compile(program)
+    return sum(scopes.get(scope, (0.0, 0))[0]
+               for prog, scopes in r["scopes"].items() if rx.search(prog))
+
+
 def tables(r: dict) -> list[str]:
     """The tables of a reduction, as lines of text."""
     out = [f"window_s={r['window_s']:.6f}"]

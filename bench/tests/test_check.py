@@ -26,8 +26,9 @@ SEED = 3
 def _cell(config: str, traffic: str = "tiny_closed") -> SPEC.Cell:
     load = lambda n: SPEC.load_json(os.path.join(DATA, n + ".json"))
     b = SPEC.benchmark()
-    return SPEC.Cell("tiny", 1, load(config), load(traffic),
-                     b["end_to_end"], b["per_layer"])
+    conf = load(config)
+    return SPEC.Cell("tiny", 1, conf, load(traffic), b["end_to_end"],
+                     b["per_layer"], SPEC.arch_of(conf))
 
 
 def _run(cell, control=False, seed=SEED):

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from bench import roofline as RL
+from bench import spans as SPANS
 from bench import spec as SPEC
 from bench import stats as ST
 from bench import trace_reduce as TR
@@ -268,6 +269,7 @@ def test_every_cell_finds_its_parts_by_name():
     for w in b["workloads"]:
         cell = SPEC.cell(w["name"])
         assert cell.config["name"] == w["config"]
+        assert callable(cell.arch.logits_rows)
         assert cell.traffic["kind"] in ("closed_loop", "open_loop")
         names = {m["name"] for m in cell.end_to_end}
         assert "setup_s" in names and len(names) >= 2
@@ -285,10 +287,11 @@ def test_every_cell_finds_its_parts_by_name():
 
 def test_a_new_cell_and_metric_are_data_files_and_an_entry(tmp_path):
     bench = tmp_path / "bench"
-    for sub in ("configs", "traffic", "metrics"):
+    for sub in ("configs", "traffic", "metrics", "arch"):
         (bench / sub).mkdir(parents=True)
     shutil.copy(SPEC.config_file("stablelm-3b"),
                 bench / "configs" / "new-model.json")
+    shutil.copy(SPEC.arch_file("dense"), bench / "arch" / "dense.py")
     (bench / "traffic" / "new_mix.json").write_text(json.dumps(
         {"kind": "closed_loop", "clients": 2, "requests_per_client": 1,
          "prompt_len": {"median": 8, "sigma": 0, "min": 8, "max": 8},
@@ -305,6 +308,7 @@ def test_a_new_cell_and_metric_are_data_files_and_an_entry(tmp_path):
     cell = SPEC.cell("new-model.new_mix", root=str(tmp_path),
                      bench_dir=str(bench))
     assert cell.config["registry"] == "stablelm-3b"
+    assert cell.arch.dims(cell.config["model"])["L"] == 32
     assert cell.traffic["clients"] == 2
     assert [m["name"] for m in cell.per_layer] == ["new_metric"]
     assert SPEC.reader("new_metric", bench_dir=str(bench))({"x": 21}) == 42
@@ -349,8 +353,9 @@ def _ctx(config="deepseek-coder-33b-pp8"):
     work = Work(decode_steps=[[2049, 2100], [2050, 2101]],
                 chunks=[(0, 128), (1920, 128)])
     return {
-        "model": CONFIGS[config]["model"], "kv_dtype": "same",
-        "peak": RL.peaks("TPU v5 lite"), "trace": TR.reduce(_trace()),
+        "model": CONFIGS[config]["model"], "arch": SPEC.arch("dense"),
+        "kv_dtype": "same", "peak": RL.peaks("TPU v5 lite"),
+        "trace": TR.reduce(_trace()), "spans": SPANS.reduce(_trace()),
         "work": work, "admitted_in_window": 4,
         "counters": {"decode_steps": 50, "occupancy_sum": 40.0,
                      "prefill_tokens": 8192, "prefill_tokens_saved": 0},
@@ -382,7 +387,31 @@ def test_readers_compute_from_counters_trace_and_shapes():
 
 
 def test_readers_return_nothing_without_a_trace():
-    ctx = dict(_ctx(), trace=None, admitted_in_window=0)
+    ctx = dict(_ctx(), trace=None, spans=None, admitted_in_window=0)
     ctx["counters"] = dict(ctx["counters"], decode_steps=0)
     for m in SPEC.benchmark()["per_layer"]:
         assert SPEC.reader(m["name"])(ctx) is None, m["name"]
+
+
+def test_span_readers_take_the_engine_ticks_and_scopes():
+    ctx = _ctx()
+    ctx["spans"] = {
+        "ticks": [0.002, 0.003, 0.004],
+        "scopes": {
+            "jit_serve_step": {"engine.sample": [2e-6, 40],
+                               "engine.kv_write": [6e-6, 80],
+                               "(no scope)": [1e-6, 9]},
+            "jit_suffix_chunk": {"engine.kv_write": [9.0, 1]},
+        },
+    }
+    read = lambda name: SPEC.reader(name)(ctx)
+    assert read("tick_host_ms.decode") == pytest.approx(3.0)
+    # per run of the serve step: the synthetic trace runs it once; the
+    # prefill chunk's own writes are not the step's
+    assert read("sampler_ms.decode") == pytest.approx(2e-3)
+    assert read("kv_write_ms.decode") == pytest.approx(6e-3)
+    # a step with no op under the scope has nothing to read
+    del ctx["spans"]["scopes"]["jit_serve_step"]["engine.sample"]
+    assert read("sampler_ms.decode") is None
+    ctx["spans"]["ticks"] = []
+    assert read("tick_host_ms.decode") is None

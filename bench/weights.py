@@ -1,12 +1,14 @@
 """Seeded random weights, made by the benchmark and handed to the program.
 
-The tree has the layout the serving engine takes (``embed``,
-``final_norm``, ``head`` and the layer stack under ``units/l0``); the
-reference reads the same arrays by the same names.  Matrices are drawn
-N(0, 1/fan_in) and stored in bfloat16, the type they are served in; norm
-scales are 0.1·N(0, 1) in float32 and enter as ``x · (1 + scale)``.  All
-of it is made on the device in one jitted program, one layer at a time so
-that no float32 copy of a whole stack is ever live.
+The tree has the layout the serving engine takes; an architecture's
+``layout`` gives it, the dense one's below (``embed``, ``final_norm``,
+``head`` and the layer stack under ``units/l0``).  The reference reads
+the same arrays by the same names.  Matrices are drawn N(0, 1/fan_in)
+and stored in the type they are served in (bfloat16); norm scales are
+0.1·N(0, 1) in float32 and enter as ``x · (1 + scale)``.  All of it is
+made on the device in one jitted program, a stack one leading index at a
+time (a layer, then an expert) so that no float32 copy of more than one
+matrix is ever live.
 """
 
 from __future__ import annotations
@@ -68,10 +70,13 @@ def _leaf(key, shape, dtype, fan_in):
     def draw(k, shp):
         return (jax.random.normal(k, shp, jnp.float32) * std).astype(dtype)
 
-    if len(shape) == 3:  # a layer stack: one layer live in float32 at a time
-        keys = jax.random.split(key, shape[0])
-        return jax.lax.map(lambda k: draw(k, shape[1:]), keys)
-    return draw(key, shape)
+    def stack(k, shp):  # one leading index live in float32 at a time
+        if len(shp) < 3:
+            return draw(k, shp)
+        keys = jax.random.split(k, shp[0])
+        return jax.lax.map(lambda ki: stack(ki, shp[1:]), keys)
+
+    return stack(key, shape)
 
 
 def _nest(flat: dict) -> dict:
@@ -97,10 +102,9 @@ def _maker(items: tuple):
     return jax.jit(make)
 
 
-def make_weights(model: dict, seed: int) -> dict:
-    """The weights of ``model`` for ``seed``, on the default device."""
-    items = tuple(sorted(layout(model).items()))
-    return _maker(items)(seed_key(seed))
+def make(lay: dict, seed: int) -> dict:
+    """The weights of layout ``lay`` for ``seed``, on the default device."""
+    return _maker(tuple(sorted(lay.items())))(seed_key(seed))
 
 
 def layer(weights: dict, i: int) -> dict:
